@@ -73,6 +73,9 @@ def main() -> None:
                     "run the jax backend (needs >= tp local devices)")
     args = ap.parse_args()
 
+    from repro.serving.run import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import check as checkmod
     from benchmarks.common import save
     from benchmarks.cluster_sweep import ALL as CLUSTER

@@ -12,8 +12,9 @@ vLLM-style FCFS.
 
 --backend jax: the SAME engine and schedulers drive REAL JAX execution —
 a reduced model decoding on a device-resident paged KV cache (Pallas
-paged attention, interpret mode on CPU) — over a length-capped workload
-that fits the device page pool.  Step times are measured wall time.
+paged attention: compiled on a TPU, interpreted elsewhere) — over a
+length-capped workload that fits the device page pool.  Step times are
+measured wall time.
 
 --tp N (jax backend): execute tensor-parallel over an N-device ('model',)
 mesh — Megatron-sharded weights, KV-head-sharded page pool, all-reduced
@@ -38,7 +39,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 from repro.serving.engine import EngineConfig                # noqa: E402
 from repro.serving.run import (BackendSpec, ClusterSpec,     # noqa: E402
                                ExperimentSpec, TelemetrySpec,
-                               make_backend, run, run_cluster)
+                               enable_compile_cache, make_backend, run,
+                               run_cluster)
 from repro.serving.workload import WorkloadSpec              # noqa: E402
 
 
@@ -94,6 +96,7 @@ def main() -> None:
                     "streams are byte-identical to the colocated run "
                     "(CI diffs the digests)")
     args = ap.parse_args()
+    enable_compile_cache()
     roles = None
     if args.disagg:
         try:

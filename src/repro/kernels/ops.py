@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels execute with ``interpret=True`` (Pallas
-interpreter runs the kernel body in Python — correctness validation).  On a
-real TPU set ``interpret=False`` (default resolves by backend).
+``_default_interpret()`` is the one platform check that decides how every
+Pallas kernel runs: compiled on a TPU, interpreted (the kernel body runs as
+plain JAX ops — correctness validation) on any other backend.  The serving
+backend and these wrappers resolve ``interpret`` through it.
 """
 
 from __future__ import annotations
@@ -18,6 +19,7 @@ from repro.kernels.paged_attention import (  # noqa: F401  (re-exported)
 
 
 def _default_interpret() -> bool:
+    """True unless JAX's default backend is a TPU."""
     return jax.default_backend() != "tpu"
 
 

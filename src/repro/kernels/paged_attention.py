@@ -317,13 +317,14 @@ def fused_verify_attention(q, k_new, v_new, k_pages, v_pages, block_tables,
     """
     if interpret:
         return _verify_unrolled(q, k_new, v_new, k_pages, v_pages,
-                                block_tables, pos0, widths, scale=scale)
+                                block_tables, pos0, widths, scale=scale,
+                                interpret=True)
     return _verify_multirow(q, k_new, v_new, k_pages, v_pages, block_tables,
-                            pos0, widths, scale=scale, interpret=False)
+                            pos0, widths, scale=scale)
 
 
 def _verify_unrolled(q, k_new, v_new, k_pages, v_pages, block_tables,
-                     pos0, widths, *, scale=None):
+                     pos0, widths, *, scale=None, interpret: bool = False):
     """Row-chained verification: the exact ``fused_decode_attention``
     program applied W times through the aliased pool.  Rows at or past a
     lane's width run with an all-scrap table (the same retired-lane
@@ -339,7 +340,7 @@ def _verify_unrolled(q, k_new, v_new, k_pages, v_pages, block_tables,
         tab_s = jnp.where(widths[:, None] > s, block_tables, scrap)
         o_s, kp, vp = fused_decode_attention(
             q[:, s], k_new[:, s], v_new[:, s], kp, vp, tab_s, pos0 + s,
-            scale=scale, interpret=True)
+            scale=scale, interpret=interpret)
         outs.append(o_s)
     return jnp.stack(outs, axis=1), kp, vp
 
@@ -357,8 +358,7 @@ def _verify_multirow(q, k_new, v_new, k_pages, v_pages, block_tables,
     scale = scale or D ** -0.5
 
     kernel = functools.partial(_verify_kernel, scale=scale, page=page,
-                               npages=n_max, G=G, W=W,
-                               fence_rows=interpret)
+                               npages=n_max, G=G, W=W)
 
     def kv_out_map(b, j, tab, pos0, width):
         first = pos0[b] // page

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture × input shape) cell
 against the production mesh with ShapeDtypeStruct inputs (no allocation).
 
@@ -16,9 +13,23 @@ optimized HLO's collective inventory parsed by repro.launch.roofline.
 
 import argparse
 import json
+import os
 import subprocess
 import sys
 import time
+
+_HOST_DEVICES = "--xla_force_host_platform_device_count=512"
+
+
+def _pin_to_cpu() -> None:
+    """The dry-run is a CPU tool: before JAX starts, hold this process —
+    and, through the environment they inherit, its children — to the
+    CPU backend with 512 virtual devices, so no dry-run ever claims an
+    accelerator.  Appends to ``XLA_FLAGS`` instead of replacing it."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    flags = os.environ.get("XLA_FLAGS", "")
+    if _HOST_DEVICES not in flags:
+        os.environ["XLA_FLAGS"] = f"{flags} {_HOST_DEVICES}".strip()
 
 
 def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
@@ -134,6 +145,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
 
 
 def main() -> None:
+    _pin_to_cpu()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

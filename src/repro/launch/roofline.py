@@ -31,6 +31,10 @@ PEAK_FLOPS = 197e12
 HBM_BW = 819e9
 ICI_BW = 50e9
 
+# measured-utilization denominators by ``jax.Device.device_kind``: a time
+# measured on a device missing here gets no ``mfu_measured``
+PEAK_FLOPS_BY_KIND = {"TPU v5 lite": PEAK_FLOPS}
+
 _DTYPE_BYTES = {
     "pred": 1, "s4": 0.5, "u4": 0.5, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
     "s32": 4, "u32": 4, "s64": 8, "u64": 8, "f16": 2, "bf16": 2, "f32": 4,
@@ -348,8 +352,7 @@ def roofline_terms(rec: dict) -> dict:
 def roofline_decode_step(arch: str = "tinyllama-1.1b", batch: int = 4,
                          num_blocks: int = 32, page: int = 16,
                          max_len: int = 64, repeats: int = 3,
-                         interpret: bool = True, registry=None,
-                         steps: int = 1) -> dict:
+                         registry=None, steps: int = 1) -> dict:
     """Profile one paged decode dispatch end-to-end (DESIGN.md §9, §10).
 
     Lowers+compiles the backend's jitted ``decode_paged`` at the padded
@@ -363,14 +366,14 @@ def roofline_decode_step(arch: str = "tinyllama-1.1b", batch: int = 4,
     kernel + on-device sampling, ``steps`` micro-steps per dispatch) and
     carries the before/after pair: ``multi_measured_s`` (whole window),
     ``multi_measured_s_per_token``, and ``multi_speedup_per_token`` vs the
-    single-step reference dispatch — the numbers the decode_speed bench
-    JSON reports at workload granularity.
+    single-step dispatch that returns logits to the host.
 
-    Pallas-opacity: with ``interpret=False`` the attention kernel can lower
-    to an opaque custom-call the HLO walker cannot cost; the record then
-    carries ``hlo_opaque=True`` and the HLO-derived terms are lower bounds
-    (interpret mode traces the kernel into plain HLO and stays fully
-    costable — hence the default)."""
+    Pallas-opacity: on a TPU the attention kernel compiles to an opaque
+    custom-call the HLO walker cannot cost; the record then carries
+    ``hlo_opaque=True`` and the HLO-derived terms are lower bounds
+    (interpret mode, elsewhere, traces the kernel into plain HLO and
+    stays fully costable).  ``mfu_measured`` is reported only for a
+    device whose peak is in ``PEAK_FLOPS_BY_KIND``."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -381,7 +384,7 @@ def roofline_decode_step(arch: str = "tinyllama-1.1b", batch: int = 4,
 
     obs = registry if registry is not None else NULL
     be = PagedJaxBackend(arch, num_blocks=max(num_blocks, batch), page=page,
-                         max_len=max_len, seed=0, interpret=interpret)
+                         max_len=max_len, seed=0)
     B = _bucket(batch, lo=1)
     # one resident page of context per row (position page-1), distinct
     # pages so the dispatch gathers/scatters like a live mixed batch
@@ -410,9 +413,9 @@ def roofline_decode_step(arch: str = "tinyllama-1.1b", batch: int = 4,
         best = min(best, _time.perf_counter() - t0)
     rec["measured_s"] = best
     rec.update(roofline_terms(rec))
-    # measured MFU against the reference accelerator's peak — a *bound
-    # check* number (CPU runs will be far below mfu_bound)
-    rec["mfu_measured"] = rec["model_flops"] / (best * PEAK_FLOPS)
+    peak = PEAK_FLOPS_BY_KIND.get(jax.devices()[0].device_kind)
+    if peak is not None:
+        rec["mfu_measured"] = rec["model_flops"] / (best * peak)
     rec.update(arch=arch, batch=B, page=page)
 
     if steps > 1:
@@ -468,8 +471,6 @@ def main(argv=None) -> int:
     ap.add_argument("--steps", type=int, default=1,
                     help="also profile the §10 multi-step scan dispatch "
                     "at this horizon (before/after pair in the record)")
-    ap.add_argument("--no-interpret", action="store_true",
-                    help="compiled Pallas kernels (HLO may be opaque)")
     ap.add_argument("--metrics-out", default=None,
                     help="directory for registry snapshots (DESIGN.md §9)")
     args = ap.parse_args(argv)
@@ -481,8 +482,7 @@ def main(argv=None) -> int:
     rec = roofline_decode_step(
         arch=args.arch, batch=args.batch, num_blocks=args.num_blocks,
         page=args.page, max_len=args.max_len, repeats=args.repeats,
-        interpret=not args.no_interpret, registry=registry,
-        steps=args.steps)
+        registry=registry, steps=args.steps)
     print(f"== decode-step roofline: {args.arch} B={rec['batch']} "
           f"page={rec['page']}"
           + (" [HLO opaque: custom-call kernels]" if rec["hlo_opaque"]
@@ -493,7 +493,7 @@ def main(argv=None) -> int:
     if args.steps > 1:
         keys += ["multi_steps", "multi_measured_s",
                  "multi_measured_s_per_token", "multi_speedup_per_token"]
-    for k in keys:
+    for k in (k for k in keys if k in rec):
         v = rec[k]
         print(f"   {k:<26} {v:.4g}" if isinstance(v, float)
               else f"   {k:<26} {v}")

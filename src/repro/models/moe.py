@@ -23,11 +23,6 @@ import jax.numpy as jnp
 
 from repro.models.layers import silu
 
-try:  # jax >= 0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from jax.sharding import PartitionSpec as P
 
 
@@ -152,16 +147,10 @@ def moe_ep(x, p, cfg, ctx):
                            ep_size=mesh.shape[ctx.ep_axis],
                            gather_axis=ctx.fsdp_axis,
                            gather_mode=gather_mode, fsdp_size=fsdp)
-    try:
-        sm = _shard_map(fn, mesh=mesh,
-                        in_specs=(xspec, P(None, None), wspec_in, wspec_in,
-                                  wdspec_in),
-                        out_specs=xspec, check_vma=False)
-    except TypeError:  # older jax spells it check_rep
-        sm = _shard_map(fn, mesh=mesh,
-                        in_specs=(xspec, P(None, None), wspec_in, wspec_in,
-                                  wdspec_in),
-                        out_specs=xspec, check_rep=False)
+    sm = jax.shard_map(fn, mesh=mesh,
+                       in_specs=(xspec, P(None, None), wspec_in, wspec_in,
+                                 wdspec_in),
+                       out_specs=xspec, check_vma=False)
     return sm(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
 

@@ -1,6 +1,6 @@
 """PagedJaxBackend: real JAX execution behind the Backend protocol.
 
-A reduced model genuinely prefills and decodes on device through the
+A model genuinely prefills and decodes on device through the
 unified Model API (``prefill_paged`` / ``decode_paged``) against a single
 device-resident paged KV cache.  Block tables come from the engine's
 ``BlockManager`` — the same allocator that models KV pressure for the
@@ -57,7 +57,7 @@ from __future__ import annotations
 
 import functools
 import time
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
@@ -65,6 +65,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.archs import reduced_config
+from repro.configs.base import ModelConfig
+from repro.kernels.ops import _default_interpret
 from repro.launch.sharding import (paged_page_specs, paged_param_specs,
                                    paged_tp_plan, serving_tp_ctx)
 from repro.models.model import build_model
@@ -83,13 +85,15 @@ class PagedJaxBackend(Backend):
     supports_multi_step = True
     supports_spec_decode = True
 
-    def __init__(self, arch: str = "tinyllama-1.1b", num_blocks: int = 64,
-                 page: int = 16, max_len: int = 128, seed: int = 0,
-                 temperature: float = 0.0, top_k: int = 0,
-                 overhead: float = 1e-4, interpret: bool = True,
-                 tp: int = 1, devices: Optional[Sequence] = None,
-                 fused: bool = True, drafter=None):
-        self.cfg = reduced_config(arch)
+    def __init__(self, arch: Union[str, ModelConfig] = "tinyllama-1.1b",
+                 num_blocks: int = 64, page: int = 16, max_len: int = 128,
+                 seed: int = 0, temperature: float = 0.0, top_k: int = 0,
+                 overhead: float = 1e-4, tp: int = 1,
+                 devices: Optional[Sequence] = None, fused: bool = True,
+                 drafter=None):
+        # an arch NAME serves its reduced CPU-sized variant; a ModelConfig
+        # (e.g. ``get_config(name)``, the published widths) is served as is
+        self.cfg = reduced_config(arch) if isinstance(arch, str) else arch
         self.tp = max(int(tp), 1)
         self.plan = paged_tp_plan(self.cfg, self.tp)
         if self.tp > 1:
@@ -109,7 +113,6 @@ class PagedJaxBackend(Backend):
             raise ValueError(
                 f"{arch}: paged serving needs a pure-attention stack with "
                 "rope/none positions (recurrent mixers have no paged state)")
-        self.params = self.model.init(jax.random.PRNGKey(seed))
         self.page = page
         self.max_len = max_len
         self.n_max = -(-max_len // page)         # block-table width
@@ -118,11 +121,9 @@ class PagedJaxBackend(Backend):
         # engine allocates from is the MESH-WIDE aggregate
         pool = num_blocks * (self.tp if self.plan["attn"] else 1)
         self.scrap = pool                        # pad rows write here
-        # +1: the scrap page lives at the end of the pool, outside the
-        # BlockManager's 0..pool-1 range
-        self.pages = self.model.init_paged_caches(pool + 1, page)
         self.overhead = overhead
-        self.interpret = interpret
+        # Pallas kernels compile on a TPU and are interpreted elsewhere
+        self.interpret = _default_interpret()
         self.fused = bool(fused)
         self.sampler = Sampler(temperature=temperature, top_k=top_k,
                                seed=seed)
@@ -154,15 +155,21 @@ class PagedJaxBackend(Backend):
         # reports; compile time lands in measured step time regardless)
         self._shapes: set = set()
         self._page_shardings = None
+        key = jax.random.PRNGKey(seed)
+        # +1: the scrap page lives at the end of the pool, outside the
+        # BlockManager's 0..pool-1 range
         if self.mesh is None:
+            self.params = self.model.init(key)
+            self.pages = self.model.init_paged_caches(pool + 1, page)
             self._prefill = jax.jit(self.model.prefill_paged)
             self._prefill_many = jax.jit(self._prefill_many_impl)
-            # two-dispatch single-step reference (append + attend kernels
-            # separately, host sampling) — kept for parity tests/roofline
+            # one decode step returning logits (host sampling) — the
+            # roofline profile and logit checks read it
             self._decode = jax.jit(functools.partial(
-                self.model.decode_paged, interpret=interpret))
+                self.model.decode_paged, interpret=self.interpret,
+                fused=self.fused))
         else:
-            self._build_sharded_step_fns()
+            self._build_sharded_step_fns(key, pool + 1)
 
         # engine-facing geometry (BlockManager mirrors the device pool).
         # kv_shard_degree is the factor each PAGE is split by across the
@@ -192,38 +199,47 @@ class PagedJaxBackend(Backend):
             "jax_recompile_total",
             "new padded dispatch shapes (XLA compiles)")
 
-    def _build_sharded_step_fns(self) -> None:
-        """jit(shard_map(...)) wrappers around the paged entry points.
+    def _build_sharded_step_fns(self, key, n_pages: int) -> None:
+        """Resident-sharded weights and page pool, and jit(shard_map(...))
+        wrappers around the paged entry points.
 
-        Weights and the page pool are placed resident-sharded once; every
-        other operand (tokens, positions, block tables) is replicated.
-        ``check_rep=False``: the psums inside attention/MLP make the
-        activations replicated again, which shard_map can't prove."""
-        from jax.experimental.shard_map import shard_map
-        pspecs = paged_param_specs(self.cfg, self.tp, self.params)
-        gspecs = paged_page_specs(self.cfg, self.tp, self.pages)
+        Weights and pool are generated directly into their shardings, so
+        neither ever lands whole on one device (a tp-way pool holds tp×
+        one device's pages).  Every other operand (tokens, positions,
+        block tables) is replicated.  ``check_vma=False``: the psums
+        inside attention/MLP make the activations replicated again, which
+        shard_map can't prove."""
+        pspecs = paged_param_specs(self.cfg, self.tp,
+                                   jax.eval_shape(self.model.init, key))
+        gspecs = paged_page_specs(
+            self.cfg, self.tp, self.model.paged_cache_specs(n_pages,
+                                                            self.page))
         self._pspecs, self._gspecs = pspecs, gspecs
         sh = lambda tree: jax.tree.map(
             lambda s: NamedSharding(self.mesh, s), tree,
             is_leaf=lambda x: isinstance(x, P))
         self._param_shardings = sh(pspecs)
         self._page_shardings = sh(gspecs)
-        self.params = jax.device_put(self.params, self._param_shardings)
-        self.pages = jax.device_put(self.pages, self._page_shardings)
-        self._prefill = jax.jit(shard_map(
+        self.params = jax.jit(self.model.init,
+                              out_shardings=self._param_shardings)(key)
+        self.pages = jax.jit(
+            functools.partial(self.model.init_paged_caches, n_pages,
+                              self.page),
+            out_shardings=self._page_shardings)()
+        self._prefill = jax.jit(jax.shard_map(
             self.model.prefill_paged, mesh=self.mesh,
             in_specs=(pspecs, gspecs, P(), P(), P(), P()),
-            out_specs=gspecs, check_rep=False))
-        self._prefill_many = jax.jit(shard_map(
+            out_specs=gspecs, check_vma=False))
+        self._prefill_many = jax.jit(jax.shard_map(
             self._prefill_many_impl, mesh=self.mesh,
             in_specs=(pspecs, gspecs, P(), P(), P(), P()),
-            out_specs=gspecs, check_rep=False))
-        self._decode = jax.jit(shard_map(
+            out_specs=gspecs, check_vma=False))
+        self._decode = jax.jit(jax.shard_map(
             functools.partial(self.model.decode_paged,
-                              interpret=self.interpret),
+                              interpret=self.interpret, fused=self.fused),
             mesh=self.mesh,
             in_specs=(pspecs, gspecs, P(), P(), P()),
-            out_specs=(P(), gspecs), check_rep=False))
+            out_specs=(P(), gspecs), check_vma=False))
 
     def _commit_pages(self) -> None:
         """Re-pin the pool's sharding after a host-side page mutation
@@ -275,12 +291,11 @@ class PagedJaxBackend(Backend):
             if self.mesh is None:
                 fn = jax.jit(body)
             else:
-                from jax.experimental.shard_map import shard_map
-                fn = jax.jit(shard_map(
+                fn = jax.jit(jax.shard_map(
                     body, mesh=self.mesh,
                     in_specs=(self._pspecs, self._gspecs,
                               P(), P(), P(), P(), P()),
-                    out_specs=(P(), P(), self._gspecs), check_rep=False))
+                    out_specs=(P(), P(), self._gspecs), check_vma=False))
             self._decode_n_cache[n] = fn
         return fn
 
@@ -517,12 +532,11 @@ class PagedJaxBackend(Backend):
             if self.mesh is None:
                 fn = jax.jit(self._verify_impl)
             else:
-                from jax.experimental.shard_map import shard_map
-                fn = jax.jit(shard_map(
+                fn = jax.jit(jax.shard_map(
                     self._verify_impl, mesh=self.mesh,
                     in_specs=(self._pspecs, self._gspecs,
                               P(), P(), P(), P(), P(), P()),
-                    out_specs=(P(), P(), self._gspecs), check_rep=False))
+                    out_specs=(P(), P(), self._gspecs), check_vma=False))
             self._verify_fn = fn
         return fn
 

@@ -19,6 +19,7 @@ pool), or any ``Backend`` instance."""
 from __future__ import annotations
 
 import dataclasses
+import os
 import warnings
 from typing import Callable, Dict, List, Optional, Union
 
@@ -30,6 +31,23 @@ from repro.serving.engine import EngineConfig, ServeEngine, SimBackend
 from repro.serving.metrics import (FleetSummary, Summary, summarize,
                                    summarize_fleet)
 from repro.serving.workload import WorkloadGen, WorkloadSpec
+
+
+_REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__),
+                                          "..", "..", ".."))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory: ``JAX_COMPILATION_CACHE_DIR`` when that is set,
+    else the fixed, git-ignored ``<repo>/.jax_cache`` — a stable path,
+    since a cache that moves is never hit.  Entry points call this once
+    at start-up, never at import."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        _REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _service_aware(scheduler: str) -> bool:
@@ -73,8 +91,9 @@ class BackendSpec:
     """Execution substrate: kind ("sim" | "jax" | Backend instance | None
     -> sim), constructor kwargs, an optional per-replica factory (cluster
     runs; overrides kind/kwargs), and an optional sink list that collects
-    every backend the default cluster factory builds (for fleet-wide
-    token-stream digests)."""
+    every backend the runner builds (the one replica of ``run``, or each
+    replica of the default cluster factory) so callers can read the real
+    token streams after the run."""
     kind: Union[str, Backend, None] = None
     kwargs: Optional[Dict] = None
     factory: Optional[Callable[[int], Backend]] = None
@@ -199,6 +218,8 @@ def run(exp: ExperimentSpec) -> Summary:
     backend = make_backend(exp.backend.kind,
                            _with_tp(exp.backend.kind, exp.backend.kwargs,
                                     exp.engine))
+    if exp.backend.sink is not None:
+        exp.backend.sink.append(backend)
     sched = make_scheduler(exp.scheduler, **sk)
 
     gen = WorkloadGen(exp.workload)

@@ -15,10 +15,7 @@ if "jax" not in sys.modules and \
 import numpy as np                                            # noqa: E402
 import pytest                                                 # noqa: E402
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                                   # pragma: no cover
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st     # noqa: E402
 
 from repro.cluster.autoscaler import (Autoscaler,             # noqa: E402
                                       AutoscalerConfig)

@@ -14,10 +14,7 @@ Three layers under test:
      per-token SLO accounting shape) as n=1, telemetry on or off.
 """
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                                   # pragma: no cover
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 import jax.numpy as jnp
 import numpy as np

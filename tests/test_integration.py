@@ -60,3 +60,33 @@ def test_dryrun_single_cell_production_mesh():
     assert rec["status"] == "ok" and rec["chips"] == 256
     assert rec["hlo_flops_per_chip"] > 0
     assert rec["coll_bytes_per_chip"] > 0
+
+
+def test_compile_cache_follows_env_else_fixed_repo_path(monkeypatch):
+    import jax
+
+    from repro.serving.run import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == "/elsewhere/cache"
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = enable_compile_cache()
+        root = os.path.abspath(os.path.join(os.path.dirname(__file__),
+                                            ".."))
+        assert path == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_dryrun_pins_itself_to_cpu_and_keeps_xla_flags(monkeypatch):
+    from repro.launch import dryrun
+    monkeypatch.setenv("XLA_FLAGS", "--xla_dump_to=/dev/null")
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    dryrun._pin_to_cpu()
+    dryrun._pin_to_cpu()
+    assert os.environ["JAX_PLATFORMS"] == "cpu"
+    assert os.environ["XLA_FLAGS"] == (
+        "--xla_dump_to=/dev/null --xla_force_host_platform_device_count=512")

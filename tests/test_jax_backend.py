@@ -204,3 +204,23 @@ def test_cluster_two_replicas_real_execution():
     assert s.goodput_frac > 0
     _, texts2 = run_once()
     assert texts == texts2
+
+
+def test_backend_builds_a_given_config_and_resolves_interpret():
+    """An arch name serves its reduced CPU variant; a ModelConfig (for
+    example the published widths) is served as given.  ``interpret``
+    comes from the platform, never from the caller."""
+    import dataclasses
+
+    import jax
+
+    from repro.configs.archs import reduced_config
+    by_name = PagedJaxBackend("tinyllama-1.1b", num_blocks=2, page=16,
+                              max_len=32)
+    assert by_name.cfg == reduced_config("tinyllama-1.1b")
+    cfg = dataclasses.replace(by_name.cfg, d_model=32, dtype="bfloat16")
+    be = PagedJaxBackend(cfg, num_blocks=2, page=16, max_len=32)
+    assert be.cfg is cfg
+    assert be.params["embed"].shape == (cfg.vocab_size, 32)
+    assert be.params["embed"].dtype == jax.numpy.bfloat16
+    assert be.interpret == (jax.default_backend() != "tpu")

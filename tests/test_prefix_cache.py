@@ -4,10 +4,7 @@ workloads, reclaimable-aware KV pressure, and the prefix-affinity router."""
 
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:   # property tests degrade to sampling
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.baselines import make_scheduler
 from repro.serving.engine import EngineConfig, ServeEngine, SimBackend

@@ -99,6 +99,8 @@ def test_roofline_decode_step_smoke():
     assert not rec["hlo_opaque"] and rec["hlo_flops_per_chip"] > 0
     assert reg.value_of("roofline_decode_measured_s", batch="1") \
         == rec["measured_s"]
+    # a measured utilization needs the device's own peak: none for a CPU
+    assert "mfu_measured" not in rec
 
 
 def test_parse_hlo_handles_tuple_types_with_comments():

@@ -2,10 +2,7 @@
 arbitrary request states (hypothesis), pacing/reserve/preemption behaviours."""
 
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:   # property tests degrade to sampling
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.baselines import make_scheduler
 from repro.core.scheduler import EngineView, TempoScheduler

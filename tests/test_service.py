@@ -3,10 +3,7 @@
 import math
 
 import pytest
-try:
-    from hypothesis import given, strategies as st
-except ModuleNotFoundError:   # property tests degrade to sampling
-    from _hypothesis_fallback import given, strategies as st
+from hypothesis import given, strategies as st
 
 from repro.core.service import ServiceModel
 from repro.serving.request import Request, SLOSpec

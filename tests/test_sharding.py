@@ -5,10 +5,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:   # property tests degrade to sampling
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import get_config

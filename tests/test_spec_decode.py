@@ -8,10 +8,7 @@ path uses."""
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                                   # pragma: no cover
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.baselines import make_scheduler
 from repro.core.slo_tracker import StepCostModel
