@@ -1,0 +1,7 @@
+"""Device: share of the traced window in which no operation ran on the
+device (1 - union of the operations' intervals / window), in percent."""
+
+
+def read(ctx):
+    red = ctx["trace"]
+    return 100.0 * (1.0 - red["busy_s"] / red["window_s"])
