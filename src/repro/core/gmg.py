@@ -53,6 +53,7 @@ from typing import Dict, List, Optional, Tuple
 
 from repro.core.scheduler import (AnalyzedSchedulerBase, Decision,
                                   EngineView)
+from repro.obs import span
 from repro.serving.request import ReqState, Request
 
 # dispatch order is by group *rank*; the tuple order here is the margin
@@ -363,14 +364,22 @@ class GroupedMarginScheduler(AnalyzedSchedulerBase):
                    - self.tracker.est_step_time(batch, ctx), 1e-6)
 
     def schedule(self, view: EngineView) -> Decision:
-        reqs = [r for r in view.requests.values()
-                if r.state != ReqState.FINISHED]
-        for rid in self._running:
-            r = view.requests.get(rid)
-            if r is not None and r.state != ReqState.FINISHED:
-                self.refine(r, view)
-        self._bp = self._batch_profile(view)
-        self._refresh_groups(view, reqs)
+        with span("sched.refine"):
+            reqs = [r for r in view.requests.values()
+                    if r.state != ReqState.FINISHED]
+            for rid in self._running:
+                r = view.requests.get(rid)
+                if r is not None and r.state != ReqState.FINISHED:
+                    self.refine(r, view)
+        with span("sched.group"):
+            self._bp = self._batch_profile(view)
+            self._refresh_groups(view, reqs)
+        with span("sched.fill"):
+            return self._fill(view, reqs)
+
+    def _fill(self, view: EngineView, reqs: List[Request]) -> Decision:
+        """Decode slots, sheds and the prefill budget for this step, from
+        the margin groups ``schedule`` refreshed."""
         now = view.now
 
         decodable = [r for r in reqs if r.prefill_remaining == 0

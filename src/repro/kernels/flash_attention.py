@@ -91,7 +91,7 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 512,
             _vmem((bq,), jnp.float32),
             _vmem((bq, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="flash_attention",
     )(q, k, v)
 
 

@@ -208,7 +208,7 @@ def fused_decode_attention(q, k_new, v_new, k_pages, v_pages, block_tables,
                    jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
                    jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
         input_output_aliases={6: 1, 7: 2},
-        interpret=interpret,
+        interpret=interpret, name="fused_decode_attention",
     )(block_tables, ctx_lens, positions.astype(jnp.int32),
       q, k_new, v_new, k_pages, v_pages)
 
@@ -401,7 +401,7 @@ def _verify_multirow(q, k_new, v_new, k_pages, v_pages, block_tables,
                    jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
                    jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
         input_output_aliases={6: 1, 7: 2},
-        interpret=interpret,
+        interpret=interpret, name="fused_verify_attention",
     )(block_tables, pos0.astype(jnp.int32), widths.astype(jnp.int32),
       q, k_new, v_new, k_pages, v_pages)
 
@@ -491,5 +491,5 @@ def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        interpret=interpret,
+        interpret=interpret, name="paged_attention",
     )(block_tables, ctx_lens, q, k_pages, v_pages)

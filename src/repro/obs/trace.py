@@ -16,11 +16,17 @@ attributes, and export two ways:
 Like the metrics registry, the module-level :data:`NULL_TRACER` is the
 disabled default: ``event()`` is a no-op, nothing is stored, and tracing
 never feeds back into scheduling, so digests are identical on/off.
+
+Separately, :func:`span` marks the phases of one engine step (the names in
+:data:`SPANS`) as JAX profiler annotations: on the profiler's wall clock,
+beside the device's planes, recorded only while a profiler trace is live.
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
+import sys
 from typing import Dict, List, Optional
 
 # terminal event names: a complete trace ends a request with exactly one
@@ -163,3 +169,44 @@ class NullTracer:
 
 
 NULL_TRACER = NullTracer()
+
+
+# ---------------------------------------------------------------------------
+# Host spans on the profiler's clock (DESIGN.md §9)
+# ---------------------------------------------------------------------------
+# The phases of one serving step.  Each is opened once per phase (never per
+# request) inside a call to ``ServeEngine.step_once``.
+SPANS = {
+    "engine.admit": "admitting queued arrivals and landing handoffs",
+    "engine.plan": "the step's KV allocation and eviction, COW forks, block "
+                   "tables and queued prefill chunks",
+    "engine.account": "step log, tracker update, first-token and finish "
+                      "processing after the step's dispatch",
+    "engine.gc": "a Python garbage collection during an engine step",
+    "sched.refine": "refining the running requests' length estimates",
+    "sched.group": "batch profile, margins and margin groups",
+    "sched.fill": "greedy fill, backfill and prefill budget, up to the "
+                  "Decision",
+    "backend.stage": "packing prefill lanes and decode staging arrays",
+    "backend.launch": "jitted calls until they return (argument transfer "
+                      "and enqueue; compiles land here)",
+    "backend.wait": "the host blocked on the device",
+    "backend.unpack": "appending sampled tokens to the streams",
+}
+
+_NO_SPAN = contextlib.nullcontext()
+_annotation = None
+
+
+def span(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named ``name`` (one of
+    :data:`SPANS`): about a microsecond with no trace live.  A process
+    that has not imported JAX can have no trace live, so it gets a no-op
+    context and JAX stays unimported."""
+    global _annotation
+    if _annotation is None:
+        if "jax" not in sys.modules:
+            return _NO_SPAN
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation(name)

@@ -20,6 +20,7 @@ iteration-level scheduling."""
 from __future__ import annotations
 
 import dataclasses
+import gc
 import heapq
 import math
 from typing import Callable, Dict, List, Optional, Tuple
@@ -27,7 +28,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.core.scheduler import EngineView, SchedulerBase
-from repro.obs import NULL, NULL_TRACER
+from repro.obs import NULL, NULL_TRACER, span
 # SimBackend is re-exported here for backward compatibility — most callers
 # still import it from repro.serving.engine.
 from repro.serving.backend import Backend, SimBackend  # noqa: F401
@@ -41,6 +42,31 @@ from repro.serving.workload import WorkloadGen
 # floor).  A rejected window costs its full width in forwards to emit one
 # token, so a lane whose EWMA sits under the floor is a net loss.
 SPEC_EWMA_FLOOR = 0.15
+
+
+class _GcSpans:
+    """Spans each Python garbage collection that runs inside an engine step
+    as ``engine.gc`` (one ``gc.callbacks`` hook per process).  Collections
+    between steps are left out, so every ``engine.gc`` nests inside a
+    ``step_once``."""
+
+    steps = 0                  # engine steps in progress
+    _open = None
+
+    @classmethod
+    def install(cls) -> None:
+        if cls.on_gc not in gc.callbacks:
+            gc.callbacks.append(cls.on_gc)
+
+    @classmethod
+    def on_gc(cls, phase: str, info) -> None:
+        if phase == "start":
+            if cls.steps:
+                cls._open = span("engine.gc")
+                cls._open.__enter__()
+        elif cls._open is not None:
+            cls._open.__exit__(None, None, None)
+            cls._open = None
 
 
 # ---------------------------------------------------------------------------
@@ -114,6 +140,7 @@ class ServeEngine:
         self.obs = obs if obs is not None else NULL
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self._trace = self.tracer.enabled
+        _GcSpans.install()
         scheduler.obs = self.obs
         scheduler.tracer = self.tracer
         scheduler.replica = replica
@@ -518,34 +545,39 @@ class ServeEngine:
     def admit_arrived(self) -> None:
         """Admit every queued arrival whose time has been reached, and land
         every in-flight migration whose transfer has completed."""
-        while self._pending and self._pending[0][0] <= self.now:
-            _, _, (kind, obj) = heapq.heappop(self._pending)
-            if kind == "r":
-                self._admit(obj)
-            else:
-                dag, reqs = obj
-                self.dags[dag.dag_id] = dag
-                self._on_stage_start(dag, reqs, stage=0)
-        while self._inbound and self._inbound[0][0] <= self.now:
-            _, _, req, pkg = heapq.heappop(self._inbound)
-            self.handoff_in(req, pkg)
+        with span("engine.admit"):
+            while self._pending and self._pending[0][0] <= self.now:
+                _, _, (kind, obj) = heapq.heappop(self._pending)
+                if kind == "r":
+                    self._admit(obj)
+                else:
+                    dag, reqs = obj
+                    self.dags[dag.dag_id] = dag
+                    self._on_stage_start(dag, reqs, stage=0)
+            while self._inbound and self._inbound[0][0] <= self.now:
+                _, _, req, pkg = heapq.heappop(self._inbound)
+                self.handoff_in(req, pkg)
 
     def step_once(self) -> bool:
         """Admit arrivals, jump the clock over an idle gap if needed, and
         run ONE scheduler step.  Returns False when out of work/steps."""
         if self.step >= self.cfg.max_steps:
             return False
-        self.admit_arrived()
-        if not self.has_live():
-            t = self._next_arrival_t()
-            if t is None:
-                return False
-            self.now = max(self.now, t)
+        _GcSpans.steps += 1
+        try:
             self.admit_arrived()
             if not self.has_live():
-                return False
-        self._execute(self.sched.schedule(self._view()))
-        return True
+                t = self._next_arrival_t()
+                if t is None:
+                    return False
+                self.now = max(self.now, t)
+                self.admit_arrived()
+                if not self.has_live():
+                    return False
+            self._execute(self.sched.schedule(self._view()))
+            return True
+        finally:
+            _GcSpans.steps -= 1
 
     # ------------------------------------------------------------------
     def run(self, until: Optional[float] = None, drain: bool = True):
@@ -786,10 +818,43 @@ class ServeEngine:
         self._step_swap = 0.0
         self._kv_blocked = False
         self.backend.begin_step()
-        # shed requests: dropped outright (scheduler decided the §3.1 decay
-        # left nothing worth serving and KV is under pressure).  Blocks are
-        # released BEFORE this step's allocations so the freed pages are
-        # usable immediately.
+        with span("engine.plan"):
+            (prefill_tokens, decoded_reqs, decode_ctxs, decode_tables,
+             protect) = self._plan(dec)
+
+        if self._spec_step(decoded_reqs, decode_ctxs, prefill_tokens,
+                           protect):
+            return
+
+        n = self._decode_horizon(dec, decoded_reqs, prefill_tokens, protect)
+        if n > 1:
+            # the horizon pre-allocated n tokens of block headroom per
+            # lane, which may have grown the tables — re-read them
+            decode_tables = [self.kv.block_table(r.rid)
+                             for r in decoded_reqs]
+            _, act_n = self.backend.decode_batch_n(decoded_reqs,
+                                                   decode_tables, n)
+            self._account_multi_step(decoded_reqs, decode_ctxs, act_n, n)
+            return
+
+        self.backend.decode_batch(decoded_reqs, decode_tables)
+
+        dt = self.backend.step_time(prefill_tokens, decode_ctxs)
+        dt += self._step_swap / self.cfg.swap_bw
+        self._last_step_dt = dt
+        with span("engine.account"):
+            self._account_step(dt, prefill_tokens, decoded_reqs,
+                               decode_ctxs)
+
+    def _plan(self, dec):
+        """Apply the decision's sheds and preemptions, then allocate KV for
+        its prefill chunks (queued on the backend) and its decode lanes.
+        Returns (prefill tokens, decode requests, their contexts, their
+        block tables, the rids protected from eviction this step)."""
+        # shed requests: dropped outright (scheduler decided the §3.1
+        # decay left nothing worth serving and KV is under pressure).
+        # Blocks are released BEFORE this step's allocations so the
+        # freed pages are usable immediately.
         for rid in getattr(dec, "shed", ()):
             r = self.requests.get(rid)
             if r is None or r.state == ReqState.FINISHED:
@@ -863,27 +928,14 @@ class ServeEngine:
 
         if not prefill_tokens and not decode_ctxs and self._kv_blocked:
             self._force_evict()
+        return (prefill_tokens, decoded_reqs, decode_ctxs, decode_tables,
+                protect)
 
-        if self._spec_step(decoded_reqs, decode_ctxs, prefill_tokens,
-                           protect):
-            return
-
-        n = self._decode_horizon(dec, decoded_reqs, prefill_tokens, protect)
-        if n > 1:
-            # the horizon pre-allocated n tokens of block headroom per
-            # lane, which may have grown the tables — re-read them
-            decode_tables = [self.kv.block_table(r.rid)
-                             for r in decoded_reqs]
-            _, act_n = self.backend.decode_batch_n(decoded_reqs,
-                                                   decode_tables, n)
-            self._account_multi_step(decoded_reqs, decode_ctxs, act_n, n)
-            return
-
-        self.backend.decode_batch(decoded_reqs, decode_tables)
-
-        dt = self.backend.step_time(prefill_tokens, decode_ctxs)
-        dt += self._step_swap / self.cfg.swap_bw
-        self._last_step_dt = dt
+    def _account_step(self, dt: float, prefill_tokens: int,
+                      decoded_reqs: List[Request],
+                      decode_ctxs: List[int]) -> None:
+        """Book one single-token step that took ``dt``: clock, step log,
+        histograms, the tracker's cost model, and each lane's token."""
         self.now += dt
         self.step += 1
         ctx_total = sum(decode_ctxs)
@@ -911,8 +963,14 @@ class ServeEngine:
             tr.on_step(dt, prefill_tokens, len(decoded_reqs),
                        float(ctx_total))
 
-        finished_now = []
-        for r in decoded_reqs:
+        self._on_finished(self._emit_token(decoded_reqs))
+
+    def _emit_token(self, reqs: List[Request]) -> List[Request]:
+        """Book one token for each of ``reqs`` at ``self.now``: token times,
+        first token, and finish (prefix registration, KV release).  Returns
+        the requests that finished."""
+        finished = []
+        for r in reqs:
             r.decoded += 1
             r.token_times.append(self.now)
             if r.first_token_t is None:
@@ -930,7 +988,7 @@ class ServeEngine:
                 self.kv.release(r.rid)
                 self.backend.kv_release(r.rid)
                 self.finished.append(r)
-                finished_now.append(r)
+                finished.append(r)
                 self._m_finished.inc(t=self.now)
                 self._tenant_done(r)
                 if r.decoded > 1 and r.first_token_t is not None:
@@ -940,7 +998,10 @@ class ServeEngine:
                 if self._trace:
                     self.tracer.event("finish", r.rid, self.now,
                                       self.replica, decoded=r.decoded)
-        for r in finished_now:
+        return finished
+
+    def _on_finished(self, finished: List[Request]) -> None:
+        for r in finished:
             self.sched.on_finish(r, self._view())
             if r.dag_id is not None:
                 self._maybe_advance_dag(r)
@@ -1037,65 +1098,36 @@ class ServeEngine:
         m = max(e for e, _, _ in results)
         dt_each = dt_total / m
         self._last_step_dt = dt_each
-        tr = self._tracker()
-        ctx_total = sum(decode_ctxs)
-        if tr is not None:
-            cm = getattr(tr, "cost_model", None)
-            pred = cm.predict(0, len(decoded_reqs), float(ctx_total),
-                              verify_tokens=vtok) if cm is not None \
-                else None
-            if pred is not None:
-                self.cost_residuals.append(dt_total - pred)
-                self._m_resid.observe(abs(dt_total - pred), t=self.now)
-            tr.on_step(dt_total, 0, len(decoded_reqs), float(ctx_total),
-                       verify_tokens=vtok)
-        finished_now = []
-        for s in range(m):
-            act = [r for r, (e, _, _) in zip(decoded_reqs, results)
-                   if s < e]
-            if not act:
-                break
-            self.now += dt_each
-            self.step += 1
-            self.step_log.append((self.now, 0, len(act),
-                                  sum(r.prompt_len + r.decoded
-                                      for r in act)))
-            self._m_step["decode"].observe(dt_each, t=self.now)
-            self._m_prefill_tok.observe(0, t=self.now)
-            self._m_decode_seqs.observe(len(act), t=self.now)
-            self._m_kv.set(1.0 - self.kv.available_frac, t=self.now)
-            for r in act:
-                r.decoded += 1
-                r.token_times.append(self.now)
-                if r.first_token_t is None:
-                    r.first_token_t = self.now
-                    self._m_ttft[r.slo.kind].observe(self.now - r.arrival,
-                                                     t=self.now)
-                    if self._trace:
-                        self.tracer.event("first_token", r.rid, self.now,
-                                          self.replica)
-                if r.done:
-                    r.state = ReqState.FINISHED
-                    r.finish_t = self.now
-                    if self.cfg.prefix_cache:
-                        self._prefix_register(r)
-                    self.kv.release(r.rid)
-                    self.backend.kv_release(r.rid)
-                    self.finished.append(r)
-                    finished_now.append(r)
-                    self._m_finished.inc(t=self.now)
-                    self._tenant_done(r)
-                    if r.decoded > 1 and r.first_token_t is not None:
-                        self._m_tpot[r.slo.kind].observe(
-                            (self.now - r.first_token_t) / (r.decoded - 1),
-                            t=self.now)
-                    if self._trace:
-                        self.tracer.event("finish", r.rid, self.now,
-                                          self.replica, decoded=r.decoded)
-        for r in finished_now:
-            self.sched.on_finish(r, self._view())
-            if r.dag_id is not None:
-                self._maybe_advance_dag(r)
+        with span("engine.account"):
+            tr = self._tracker()
+            ctx_total = sum(decode_ctxs)
+            if tr is not None:
+                cm = getattr(tr, "cost_model", None)
+                pred = cm.predict(0, len(decoded_reqs), float(ctx_total),
+                                  verify_tokens=vtok) if cm is not None \
+                    else None
+                if pred is not None:
+                    self.cost_residuals.append(dt_total - pred)
+                    self._m_resid.observe(abs(dt_total - pred), t=self.now)
+                tr.on_step(dt_total, 0, len(decoded_reqs), float(ctx_total),
+                           verify_tokens=vtok)
+            finished_now = []
+            for s in range(m):
+                act = [r for r, (e, _, _) in zip(decoded_reqs, results)
+                       if s < e]
+                if not act:
+                    break
+                self.now += dt_each
+                self.step += 1
+                self.step_log.append((self.now, 0, len(act),
+                                      sum(r.prompt_len + r.decoded
+                                          for r in act)))
+                self._m_step["decode"].observe(dt_each, t=self.now)
+                self._m_prefill_tok.observe(0, t=self.now)
+                self._m_decode_seqs.observe(len(act), t=self.now)
+                self._m_kv.set(1.0 - self.kv.available_frac, t=self.now)
+                finished_now += self._emit_token(act)
+            self._on_finished(finished_now)
 
     # ------------------------------------------------------------------
     # multi-step decode fast path (DESIGN.md §10)
@@ -1155,57 +1187,28 @@ class ServeEngine:
         dt_total += self._step_swap / self.cfg.swap_bw
         dt_each = dt_total / n
         self._last_step_dt = dt_each
-        tr = self._tracker()
-        cm = getattr(tr, "cost_model", None) if tr is not None else None
-        finished_now = []
-        for s in range(n):
-            act = [r for i, r in enumerate(decoded_reqs) if act_n[i][s]]
-            if not act:
-                break
-            ctx_total = sum(r.prompt_len + r.decoded for r in act)
-            self.now += dt_each
-            self.step += 1
-            self.step_log.append((self.now, 0, len(act), ctx_total))
-            self._m_step["decode"].observe(dt_each, t=self.now)
-            self._m_prefill_tok.observe(0, t=self.now)
-            self._m_decode_seqs.observe(len(act), t=self.now)
-            self._m_kv.set(1.0 - self.kv.available_frac, t=self.now)
-            if tr is not None:
-                pred = cm.predict(0, len(act), float(ctx_total)) \
-                    if cm is not None else None
-                if pred is not None:
-                    self.cost_residuals.append(dt_each - pred)
-                    self._m_resid.observe(abs(dt_each - pred), t=self.now)
-                tr.on_step(dt_each, 0, len(act), float(ctx_total))
-            for r in act:
-                r.decoded += 1
-                r.token_times.append(self.now)
-                if r.first_token_t is None:
-                    r.first_token_t = self.now
-                    self._m_ttft[r.slo.kind].observe(self.now - r.arrival,
-                                                     t=self.now)
-                    if self._trace:
-                        self.tracer.event("first_token", r.rid, self.now,
-                                          self.replica)
-                if r.done:
-                    r.state = ReqState.FINISHED
-                    r.finish_t = self.now
-                    if self.cfg.prefix_cache:
-                        self._prefix_register(r)
-                    self.kv.release(r.rid)
-                    self.backend.kv_release(r.rid)
-                    self.finished.append(r)
-                    finished_now.append(r)
-                    self._m_finished.inc(t=self.now)
-                    self._tenant_done(r)
-                    if r.decoded > 1 and r.first_token_t is not None:
-                        self._m_tpot[r.slo.kind].observe(
-                            (self.now - r.first_token_t) / (r.decoded - 1),
-                            t=self.now)
-                    if self._trace:
-                        self.tracer.event("finish", r.rid, self.now,
-                                          self.replica, decoded=r.decoded)
-        for r in finished_now:
-            self.sched.on_finish(r, self._view())
-            if r.dag_id is not None:
-                self._maybe_advance_dag(r)
+        with span("engine.account"):
+            tr = self._tracker()
+            cm = getattr(tr, "cost_model", None) if tr is not None else None
+            finished_now = []
+            for s in range(n):
+                act = [r for i, r in enumerate(decoded_reqs) if act_n[i][s]]
+                if not act:
+                    break
+                ctx_total = sum(r.prompt_len + r.decoded for r in act)
+                self.now += dt_each
+                self.step += 1
+                self.step_log.append((self.now, 0, len(act), ctx_total))
+                self._m_step["decode"].observe(dt_each, t=self.now)
+                self._m_prefill_tok.observe(0, t=self.now)
+                self._m_decode_seqs.observe(len(act), t=self.now)
+                self._m_kv.set(1.0 - self.kv.available_frac, t=self.now)
+                if tr is not None:
+                    pred = cm.predict(0, len(act), float(ctx_total)) \
+                        if cm is not None else None
+                    if pred is not None:
+                        self.cost_residuals.append(dt_each - pred)
+                        self._m_resid.observe(abs(dt_each - pred), t=self.now)
+                    tr.on_step(dt_each, 0, len(act), float(ctx_total))
+                finished_now += self._emit_token(act)
+            self._on_finished(finished_now)

@@ -55,6 +55,7 @@ mesh-wide aggregate pool.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import time
 from typing import Dict, List, Optional, Sequence, Union
@@ -70,6 +71,7 @@ from repro.kernels.ops import _default_interpret
 from repro.launch.sharding import (paged_page_specs, paged_param_specs,
                                    paged_tp_plan, serving_tp_ctx)
 from repro.models.model import build_model
+from repro.obs import span
 from repro.serving.backend import Backend, Sampler
 from repro.serving.drafter import NgramDrafter
 
@@ -79,6 +81,38 @@ def _bucket(n: int, lo: int = 8) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def _named(name: str, fun, **kw):
+    """``functools.partial(fun, **kw)`` under a stable name: JAX names a
+    compiled program after its function (``jit_<name>``, which profiles
+    show), and a bare partial compiles as ``jit__unknown``."""
+    p = functools.partial(fun, **kw)
+    p.__name__ = name
+    return p
+
+
+class _Compiles:
+    """XLA compiles and persistent-cache loads in this process, counted by
+    one listener on JAX's monitoring events, installed with the first
+    backend; each backend books the ones its own dispatches trigger
+    (``jax_compiles_total``)."""
+
+    EVENTS = ("/jax/core/compile/backend_compile_duration",
+              "/jax/compilation_cache/cache_retrieval_time_sec")
+    n = 0
+    _installed = False
+
+    @classmethod
+    def install(cls) -> None:
+        if not cls._installed:
+            jax.monitoring.register_event_duration_secs_listener(cls._on)
+            cls._installed = True
+
+    @classmethod
+    def _on(cls, event: str, duration: float, **_) -> None:
+        if event in cls.EVENTS:
+            cls.n += 1
 
 
 class PagedJaxBackend(Backend):
@@ -148,12 +182,7 @@ class PagedJaxBackend(Backend):
         self.n_prefill_dispatches = 0
         self._seed = seed
         self._t_acc = 0.0
-        self._host_t0 = 0.0
         self._pages_step = 0
-        # padded dispatch shapes seen so far — each new (kind, size) bucket
-        # is one XLA compile (the recompile-count proxy the profiler
-        # reports; compile time lands in measured step time regardless)
-        self._shapes: set = set()
         self._page_shardings = None
         key = jax.random.PRNGKey(seed)
         # +1: the scrap page lives at the end of the pool, outside the
@@ -165,9 +194,9 @@ class PagedJaxBackend(Backend):
             self._prefill_many = jax.jit(self._prefill_many_impl)
             # one decode step returning logits (host sampling) — the
             # roofline profile and logit checks read it
-            self._decode = jax.jit(functools.partial(
-                self.model.decode_paged, interpret=self.interpret,
-                fused=self.fused))
+            self._decode = jax.jit(_named(
+                "decode_step", self.model.decode_paged,
+                interpret=self.interpret, fused=self.fused))
         else:
             self._build_sharded_step_fns(key, pool + 1)
 
@@ -180,24 +209,20 @@ class PagedJaxBackend(Backend):
         self.kv_bytes = float(self.model.kv_bytes_per_token())
         self.kv_shard_degree = self.tp if self.plan["attn"] else 1
         self.attach_obs(self.obs)       # resolve no-op instruments
+        _Compiles.install()
 
     def attach_obs(self, obs) -> None:
         """Bind the run's metrics registry and pre-resolve the backend's
         instruments (DESIGN.md §9).  The engine calls this at
         construction; until then the class-level no-op registry holds."""
         self.obs = obs
-        self._m_device = obs.counter(
-            "jax_device_seconds_total",
-            "wall time inside jitted device dispatches")
-        self._m_host = obs.counter(
-            "jax_host_seconds_total",
-            "host-side step time outside device dispatches")
         self._m_pages = obs.counter(
             "jax_pages_touched_total",
             "block-table pages referenced by dispatches")
         self._m_compile = obs.counter(
-            "jax_recompile_total",
-            "new padded dispatch shapes (XLA compiles)")
+            "jax_compiles_total",
+            "XLA compiles and persistent-cache loads the dispatches "
+            "triggered")
 
     def _build_sharded_step_fns(self, key, n_pages: int) -> None:
         """Resident-sharded weights and page pool, and jit(shard_map(...))
@@ -235,8 +260,8 @@ class PagedJaxBackend(Backend):
             in_specs=(pspecs, gspecs, P(), P(), P(), P()),
             out_specs=gspecs, check_vma=False))
         self._decode = jax.jit(jax.shard_map(
-            functools.partial(self.model.decode_paged,
-                              interpret=self.interpret, fused=self.fused),
+            _named("decode_step", self.model.decode_paged,
+                   interpret=self.interpret, fused=self.fused),
             mesh=self.mesh,
             in_specs=(pspecs, gspecs, P(), P(), P()),
             out_specs=(P(), gspecs), check_vma=False))
@@ -284,10 +309,11 @@ class PagedJaxBackend(Backend):
 
     def _decode_n_fn(self, n: int):
         """Jitted (and, under tp, shard_mapped) scan dispatch for a given
-        static horizon n — cached per n; shape buckets retrace inside."""
+        static horizon n — cached per n; shape buckets retrace inside.
+        Every horizon compiles as ``jit_decode_scan``."""
         fn = self._decode_n_cache.get(n)
         if fn is None:
-            body = functools.partial(self._scan_decode, n=n)
+            body = _named("decode_scan", self._scan_decode, n=n)
             if self.mesh is None:
                 fn = jax.jit(body)
             else:
@@ -311,10 +337,15 @@ class PagedJaxBackend(Backend):
         pages, _ = jax.lax.scan(body, pages, (toks, starts, tabs, ns))
         return pages
 
-    def _track_shape(self, key) -> None:
-        if key not in self._shapes:
-            self._shapes.add(key)
-            self._m_compile.inc()
+    @contextlib.contextmanager
+    def _launch(self):
+        """``backend.launch`` around jitted calls (argument transfer and
+        enqueue); the compiles they trigger count into
+        ``jax_compiles_total``."""
+        n0 = _Compiles.n
+        with span("backend.launch"):
+            yield
+        self._m_compile.inc(_Compiles.n - n0)
 
     def _staging_bufs(self, B: int):
         bufs = self._staging.get(B)
@@ -371,7 +402,6 @@ class PagedJaxBackend(Backend):
     def begin_step(self) -> None:
         self._t_acc = 0.0
         self._pages_step = 0
-        self._host_t0 = time.perf_counter()
 
     def reset_run_state(self) -> None:
         """Forget per-request state so one backend instance can serve a
@@ -419,21 +449,19 @@ class PagedJaxBackend(Backend):
         if not q:
             return
         self._pf_queue = []
-        groups: Dict[int, list] = {}
-        for item in q:
-            groups.setdefault(item[0], []).append(item)
         t0 = time.perf_counter()
-        for C, items in groups.items():
-            self.n_prefill_dispatches += 1
-            if len(items) == 1:
-                _, toks, start, tab, n = items[0]
-                self._track_shape(("prefill", C))
-                self.pages = self._prefill(
-                    self.params, self.pages, jnp.asarray(toks)[None, :],
-                    jnp.int32(start), jnp.asarray(tab), jnp.int32(n))
-            else:
+        with span("backend.stage"):
+            groups: Dict[int, list] = {}
+            for item in q:
+                groups.setdefault(item[0], []).append(item)
+            calls = []
+            for C, items in groups.items():
+                if len(items) == 1:
+                    _, toks, start, tab, n = items[0]
+                    calls.append((self._prefill, (
+                        toks[None, :], np.int32(start), tab, np.int32(n))))
+                    continue
                 L = _bucket(len(items), lo=2)
-                self._track_shape(("prefill_many", C, L))
                 toksL = np.zeros((L, 1, C), np.int32)
                 starts = np.zeros(L, np.int32)
                 tabsL = np.full((L, self.n_max), self.scrap, np.int32)
@@ -443,10 +471,12 @@ class PagedJaxBackend(Backend):
                     starts[i] = start
                     tabsL[i] = tab
                     ns[i] = n
-                self.pages = self._prefill_many(
-                    self.params, self.pages, jnp.asarray(toksL),
-                    jnp.asarray(starts), jnp.asarray(tabsL),
-                    jnp.asarray(ns))
+                calls.append((self._prefill_many, (toksL, starts, tabsL, ns)))
+        with self._launch():
+            for fn, args in calls:
+                self.pages = fn(self.params, self.pages,
+                                *(jnp.asarray(a) for a in args))
+        self.n_prefill_dispatches += len(calls)
         self._t_acc += time.perf_counter() - t0
 
     def decode_batch(self, reqs: List, tables: List[List[int]]) -> None:
@@ -473,36 +503,40 @@ class PagedJaxBackend(Backend):
         self._flush_prefill()
         nr = len(reqs)
         B = _bucket(nr, lo=1)
-        self._track_shape(("decode", B, n))
         self._pages_step += sum(len(t) for t in tables) * n
-        toks, pos, tabs, rem, rids = self._staging_bufs(B)
-        toks[nr:] = 0
-        pos[nr:] = 0
-        tabs[nr:] = self.scrap
-        rem[nr:] = 0
-        rids[nr:] = 0
-        for i, r in enumerate(reqs):
-            gen = self.generated.setdefault(r.rid, [])
-            prompt = self.prompt_ids(r)
-            toks[i, 0] = gen[-1] if gen else prompt[-1]
-            pos[i] = r.prompt_len - 1 + r.decoded
-            tabs[i] = self._padded_table(r.rid, tables[i])
-            rem[i] = max(0, min(n, r.true_output_len - r.decoded))
-            rids[i] = r.rid & 0x7FFFFFFF
+        with span("backend.stage"):
+            toks, pos, tabs, rem, rids = self._staging_bufs(B)
+            toks[nr:] = 0
+            pos[nr:] = 0
+            tabs[nr:] = self.scrap
+            rem[nr:] = 0
+            rids[nr:] = 0
+            for i, r in enumerate(reqs):
+                gen = self.generated.setdefault(r.rid, [])
+                prompt = self.prompt_ids(r)
+                toks[i, 0] = gen[-1] if gen else prompt[-1]
+                pos[i] = r.prompt_len - 1 + r.decoded
+                tabs[i] = self._padded_table(r.rid, tables[i])
+                rem[i] = max(0, min(n, r.true_output_len - r.decoded))
+                rids[i] = r.rid & 0x7FFFFFFF
         t0 = time.perf_counter()
-        tok_n, act_n, self.pages = self._decode_n_fn(n)(
-            self.params, self.pages, jnp.asarray(toks), jnp.asarray(pos),
-            jnp.asarray(tabs), jnp.asarray(rem), jnp.asarray(rids))
-        tok_n = np.asarray(tok_n)           # ONE host sync per n tokens
-        act_n = np.asarray(act_n)
+        with self._launch():
+            tok_n, act_n, self.pages = self._decode_n_fn(n)(
+                self.params, self.pages, jnp.asarray(toks),
+                jnp.asarray(pos), jnp.asarray(tabs), jnp.asarray(rem),
+                jnp.asarray(rids))
+        with span("backend.wait"):
+            tok_n = np.asarray(tok_n)       # ONE host sync per n tokens
+            act_n = np.asarray(act_n)
         self._t_acc += time.perf_counter() - t0
         self.n_decode_dispatches += 1
-        self.n_decode_tokens += int(act_n[:nr].sum())
-        for i, r in enumerate(reqs):
-            gen = self.generated[r.rid]
-            for s in range(n):
-                if act_n[i, s]:
-                    gen.append(int(tok_n[i, s]))
+        with span("backend.unpack"):
+            self.n_decode_tokens += int(act_n[:nr].sum())
+            for i, r in enumerate(reqs):
+                gen = self.generated[r.rid]
+                for s in range(n):
+                    if act_n[i, s]:
+                        gen.append(int(tok_n[i, s]))
         return tok_n[:nr], act_n[:nr]
 
     # ------------------------------------------------------------------
@@ -555,14 +589,15 @@ class PagedJaxBackend(Backend):
             return []
         self._flush_prefill()
         drafts = []
-        for r, d in zip(reqs, depths):
-            d = int(d)
-            if d <= 0:
-                drafts.append([])
-                continue
-            gen = self.generated.setdefault(r.rid, [])
-            hist = list(self.prompt_ids(r)) + gen
-            drafts.append(self.drafter.propose(hist, d)[:d])
+        with span("backend.stage"):
+            for r, d in zip(reqs, depths):
+                d = int(d)
+                if d <= 0:
+                    drafts.append([])
+                    continue
+                gen = self.generated.setdefault(r.rid, [])
+                hist = list(self.prompt_ids(r)) + gen
+                drafts.append(self.drafter.propose(hist, d)[:d])
         # Partition: a verify window costs its full width in compute (the
         # interpret-mode lowering chains W forwards; on TPU the multi-row
         # kernel still reads W× the queries), so lanes the drafter came up
@@ -585,40 +620,43 @@ class PagedJaxBackend(Backend):
         # extra forward pass in the window, far dearer than one retrace
         # per distinct draft depth (the depth policy grants few values)
         W = 1 + max(len(drafts[i]) for i in dr_ix)
-        self._track_shape(("verify", B, W))
         self._pages_step += sum(len(tables[i]) for i in dr_ix)
-        toks = np.zeros((B, W), np.int32)
-        pos0 = np.zeros(B, np.int32)
-        widths = np.zeros(B, np.int32)   # pad lanes: width 0, all-scrap
-        tabs = np.full((B, self.n_max), self.scrap, np.int32)
-        rem = np.ones(B, np.int32)
-        rids = np.zeros(B, np.int32)
-        for j, i in enumerate(dr_ix):
-            r = reqs[i]
-            gen = self.generated[r.rid]
-            prompt = self.prompt_ids(r)
-            dr = drafts[i]
-            toks[j, 0] = gen[-1] if gen else prompt[-1]
-            toks[j, 1:1 + len(dr)] = dr
-            pos0[j] = r.prompt_len - 1 + r.decoded
-            widths[j] = 1 + len(dr)
-            tabs[j] = self._padded_table(r.rid, tables[i])
-            rem[j] = max(1, r.true_output_len - r.decoded)
-            rids[j] = r.rid & 0x7FFFFFFF
+        with span("backend.stage"):
+            toks = np.zeros((B, W), np.int32)
+            pos0 = np.zeros(B, np.int32)
+            widths = np.zeros(B, np.int32)   # pad lanes: width 0, all-scrap
+            tabs = np.full((B, self.n_max), self.scrap, np.int32)
+            rem = np.ones(B, np.int32)
+            rids = np.zeros(B, np.int32)
+            for j, i in enumerate(dr_ix):
+                r = reqs[i]
+                gen = self.generated[r.rid]
+                prompt = self.prompt_ids(r)
+                dr = drafts[i]
+                toks[j, 0] = gen[-1] if gen else prompt[-1]
+                toks[j, 1:1 + len(dr)] = dr
+                pos0[j] = r.prompt_len - 1 + r.decoded
+                widths[j] = 1 + len(dr)
+                tabs[j] = self._padded_table(r.rid, tables[i])
+                rem[j] = max(1, r.true_output_len - r.decoded)
+                rids[j] = r.rid & 0x7FFFFFFF
         t0 = time.perf_counter()
-        targets, emitted, self.pages = self._get_verify_fn()(
-            self.params, self.pages, jnp.asarray(toks), jnp.asarray(pos0),
-            jnp.asarray(widths), jnp.asarray(tabs), jnp.asarray(rem),
-            jnp.asarray(rids))
-        targets = np.asarray(targets)        # ONE host sync per step
-        emitted = np.asarray(emitted)
+        with self._launch():
+            targets, emitted, self.pages = self._get_verify_fn()(
+                self.params, self.pages, jnp.asarray(toks),
+                jnp.asarray(pos0), jnp.asarray(widths), jnp.asarray(tabs),
+                jnp.asarray(rem), jnp.asarray(rids))
+        with span("backend.wait"):
+            targets = np.asarray(targets)    # ONE host sync per step
+            emitted = np.asarray(emitted)
         self._t_acc += time.perf_counter() - t0
         self.n_decode_dispatches += 1
-        for j, i in enumerate(dr_ix):
-            r = reqs[i]
-            e = int(emitted[j])
-            self.generated[r.rid].extend(int(t) for t in targets[j, :e])
-            out[i] = (e, e - 1, len(drafts[i]))
+        with span("backend.unpack"):
+            for j, i in enumerate(dr_ix):
+                r = reqs[i]
+                e = int(emitted[j])
+                self.generated[r.rid].extend(int(t) for t in targets[j, :e])
+                out[i] = (e, e - 1, len(drafts[i]))
         # decode_batch_n already counted the plain lanes' tokens
         self.n_decode_tokens += sum(out[i][0] for i in dr_ix)
         return out
@@ -730,17 +768,10 @@ class PagedJaxBackend(Backend):
         # verify_tokens is a cost-model hint; wall time already includes
         # the verification dispatch, so it is accepted and ignored here
         self._flush_prefill()
-        # the step's one host sync: drain every dispatch queued above so
-        # _t_acc is honest device time (credited as device seconds)
+        # the step's one host sync: drain every dispatch queued above
         t0 = time.perf_counter()
-        jax.tree.leaves(self.pages)[0].block_until_ready()
+        with span("backend.wait"):
+            jax.tree.leaves(self.pages)[0].block_until_ready()
         self._t_acc += time.perf_counter() - t0
-        if self.obs.enabled:
-            # host share = wall since begin_step minus accumulated device
-            # time; real wall-clock values, metrics-only (never fed back
-            # into the simulated clock, so determinism is untouched)
-            wall = time.perf_counter() - self._host_t0
-            self._m_device.inc(self._t_acc)
-            self._m_host.inc(max(wall - self._t_acc, 0.0))
-            self._m_pages.inc(self._pages_step)
+        self._m_pages.inc(self._pages_step)
         return self.overhead + self._t_acc

@@ -145,7 +145,7 @@ def test_engine_decode_steps_byte_identical_greedy():
         engn, be, got = _run_engine(n)
         assert got == ref, f"decode_steps={n} changed the streams"
         # the fast path actually engaged: some dispatch ran n>1 micro-steps
-        assert any(k[0] == "decode" and k[2] > 1 for k in be._shapes), \
+        assert any(k > 1 for k in be._decode_n_cache), \
             f"decode_steps={n} never dispatched multi-step"
         # fewer engine->device decode dispatches, same tokens, and the SLO
         # accounting still sees one engine step per token window

@@ -224,3 +224,30 @@ def test_backend_builds_a_given_config_and_resolves_interpret():
     assert be.params["embed"].shape == (cfg.vocab_size, 32)
     assert be.params["embed"].dtype == jax.numpy.bfloat16
     assert be.interpret == (jax.default_backend() != "tpu")
+
+
+def test_compiles_counter_counts_each_new_shape_once():
+    """``jax_compiles_total`` counts the XLA compiles a backend's
+    dispatches trigger: a decode width dispatched for the first time is
+    one compile, the same width again none, another width one more."""
+    from repro.obs import MetricsRegistry
+    obs = MetricsRegistry()
+    be = PagedJaxBackend(num_blocks=8, page=16, max_len=64, seed=0)
+    be.attach_obs(obs)
+    reqs = _mk_reqs(n=2, prompt=8, out=4)
+    for r in reqs:
+        be.prefill_chunk(r, 0, r.prompt_len, [reqs.index(r)])
+    be.step_time(16, [])
+
+    def decode(rs):
+        n0 = obs.value_of("jax_compiles_total")
+        be.begin_step()
+        be.decode_batch(rs, [[reqs.index(r)] for r in rs])
+        be.step_time(0, [8] * len(rs))
+        return obs.value_of("jax_compiles_total") - n0
+
+    assert obs.value_of("jax_compiles_total") == 1     # the prefill bucket
+    assert decode(reqs[:1]) == 1
+    assert decode(reqs[:1]) == 0
+    assert decode(reqs) == 1
+    assert decode(reqs) == 0

@@ -135,7 +135,7 @@ def test_tp2_multi_step_decode_streams_identical():
         fin = eng.run()
         assert len(fin) == 2
         if decode_steps > 1:
-            assert any(k[0] == "decode" and k[2] > 1 for k in be._shapes), \
+            assert any(n > 1 for n in be._decode_n_cache), \
                 "fast path never engaged"
         return {r.rid: list(be.generated[r.rid]) for r in fin}
 
@@ -248,8 +248,7 @@ def test_tp2_streams_identical_with_telemetry():
     s_off, _ = run_obs(False)
     s_on, obs = run_obs(True)
     assert s_on == s_off
-    assert obs.value_of("jax_recompile_total") > 0
-    assert obs.value_of("jax_device_seconds_total") > 0
+    assert obs.value_of("jax_compiles_total") > 0
 
 
 @need2
