@@ -13,7 +13,9 @@ One chip (default), in order:
 
 1. kernel — the verify kernel (one grid pass over a W-row window) against
    W chained single-row ``fused_decode_attention`` calls on the same
-   inputs: outputs within ``KERNEL_TOL``, page write-backs equal.
+   inputs: outputs within ``KERNEL_TOL``, page write-backs equal; at
+   tinyllama's head widths and at Yi-34B's (``WIDE_HEADS``, where the
+   decode kernel DMAs the pool itself).
 2. serve  — a dozen requests (prompts of a few hundred tokens, outputs up
    to 128) with multi-step decode; every request must finish with tokens.
 3. logits — for two served requests, the backend's own compiled prefill
@@ -93,6 +95,7 @@ LOGIT_REL_TOL = 0.05
 # float32 and round the output to bf16 once, so they may differ by about
 # one bf16 ulp (2^-8 relative) of the largest output.
 KERNEL_TOL = 1e-2
+WIDE_HEADS = "yi-34b"      # 56 query / 8 KV heads of 128, for the kernel
 
 # Device memory kept out of the pool: activations, sampler, compile
 # scratch.
@@ -344,8 +347,9 @@ def verify_kernel_error(cfg, seed: int, *, B: int = 8, W: int = 4,
 
 def phase_kernel(cfg, seed: int) -> None:
     err, pages_equal = verify_kernel_error(cfg, seed)
-    say(f"verify kernel vs chained single-row decode: max error {err!r} "
-        f"of max |output| (tolerance {KERNEL_TOL}); page write-backs "
+    say(f"verify kernel vs chained single-row decode at {cfg.name} head "
+        f"widths: max error {err!r} of max |output| (tolerance "
+        f"{KERNEL_TOL}); page write-backs "
         f"{'equal' if pages_equal else 'DIFFER'}")
     if not np.isfinite(err) or err > KERNEL_TOL:
         fail(f"verify kernel error {err} exceeds {KERNEL_TOL}")
@@ -356,6 +360,9 @@ def phase_kernel(cfg, seed: int) -> None:
 def one_chip(args, clock, device) -> None:
     cfg = get_config(ARCH)
     phase_kernel(cfg, args.seed)
+    # 128-wide heads: the decode kernel DMAs the pool itself (64-wide
+    # ones take the grid's page windows)
+    phase_kernel(get_config(WIDE_HEADS), args.seed)
 
     nb = pool_blocks(cfg, device)
     wl = workload(args.seed)

@@ -1,16 +1,23 @@
 """Pallas TPU paged decode attention (serving hot spot).
 
-One query token per sequence attends over a paged KV cache.  The per-sequence
-block table and context lengths are SCALAR-PREFETCHED (pltpu
-PrefetchScalarGridSpec): the kv-page BlockSpec's index_map reads the table to
-pull exactly the pages this sequence owns from HBM into VMEM — the Pallas
-equivalent of PagedAttention's gather, without materialising a contiguous KV.
+One query token per sequence attends over a paged KV cache: a pool of
+``(P, page, KV, D)`` pages, each sequence owning the pages its block table
+lists (token i at ``pages[table[i // page], i % page]``; entries past the
+context point at the scrap page P-1).  Tables and positions are
+SCALAR-PREFETCHED (pltpu PrefetchScalarGridSpec), so the kernels pull
+exactly the pages a sequence owns from HBM into VMEM — the Pallas
+equivalent of PagedAttention's gather, without materialising a contiguous
+KV.  The backend and the benchmark use 16-token pages (DESIGN.md §3).
 
-Pages are 128 tokens (lane-aligned; the GPU artifact uses 16-token pages —
-TPU adaptation recorded in DESIGN.md §3).  Grid: (batch, n_pages_max); VMEM
-scratch carries online-softmax state across pages; tokens past the sequence's
-context length are masked.  Working set per step: one page (128×KV×D) + q
-(H×D) + acc (H×D) f32 ≈ 0.8 MB at KV=8, D=128 — comfortably inside VMEM.
+- ``fused_decode_attention`` (the serving path): append the step's K/V row
+  and attend, walking only each lane's live pages in blocks of 256 keys
+  (``_pages_per_block``), DMA'd double-buffered from the HBM pool, one
+  online-softmax update per block; the append writes one row.  (Heads
+  narrower than 128 lanes take one page per grid step instead.)
+- ``paged_attention`` (the ``fused=False`` reference) and
+  ``_verify_multirow`` (speculative verification on the chip): grid
+  ``(batch, n_pages_max)``, one page per step, VMEM scratch carrying the
+  online-softmax state across pages, tokens past the context masked.
 
 Tensor parallelism (DESIGN.md §8): these kernels are shard-local.  Under
 the serving shard_map each device calls them with its KV-head slice of
@@ -38,185 +45,307 @@ except Exception:  # pragma: no cover
 NEG_INF = -1e30
 
 
-def _kernel(tables_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
-            m_scr, l_scr, acc_scr, *, scale: float, page: int, npages: int,
-            G: int):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
+def _softmax_init(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32)                   # (H, D)
-    k = k_ref[0].astype(jnp.float32)                   # (page, KV, D)
-    v = v_ref[0].astype(jnp.float32)
+def _block_update(q, k, v, t0, ctx, m_scr, l_scr, acc_scr, *,
+                  scale: float):
+    """Every kernel's online-softmax update over one block of keys (a
+    page, or the fused decode walk's multi-page block): q (H, D) f32,
+    k/v (L, KV, D) the keys and values at positions t0 .. t0+L-1, state
+    m/l (H, 1) and acc (H, D) f32.  All H query rows score against all
+    L*KV key rows in ONE dot, and p·V is one dot: a row keeps only its
+    own KV head's keys (its GQA group: row // G == key % KV) before
+    ``ctx``, the rest are masked to p = 0.  This reads the block in its
+    pool layout; per-head dots would first relayout it head-major, which
+    on a v5e cost 5x the whole fused decode kernel (Yi-34B widths, 8
+    lanes of 1-4k tokens: 0.66 ms a call against 0.125 ms).  Masked
+    rows' values still meet p = 0 in p·V, so they must be finite: rows
+    of a live page, or of a buffer the kernel zeroed."""
     H, D = q.shape
-    KV = k.shape[1]
-    qg = q.reshape(KV, G, D)
-
+    L, KV, _ = k.shape
+    kf = k.astype(jnp.float32).reshape(L * KV, D)
+    vf = v.astype(jnp.float32).reshape(L * KV, D)
     s = jax.lax.dot_general(
-        qg, k, (((2,), (2,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32) * scale     # (KV, G, page)
-    pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (KV, G, page), 2)
-    live = pos < ctx_ref[b]
-    s = jnp.where(live, s, NEG_INF)
-
-    m_prev = m_scr[...]                                 # (KV, G)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2))
-    p = jnp.exp(s - m_new[..., None])
-    corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=2)
-    pv = jax.lax.dot_general(
-        p, v, (((2,), (0,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32)             # (KV, G, D)
-    acc_scr[...] = acc_scr[...] * corr[..., None] + pv
-    m_scr[...] = m_new
-
-    @pl.when(j == npages - 1)
-    def _finish():
-        out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)[..., None]
-        o_ref[0] = out.reshape(H, D).astype(o_ref.dtype)
-
-
-def _fused_kernel(tables_ref, ctx_ref, pos_ref, q_ref, kn_ref, vn_ref,
-                  k_ref, v_ref, o_ref, ko_ref, vo_ref,
-                  m_scr, l_scr, acc_scr, *, scale: float, page: int,
-                  npages: int, G: int):
-    """Append-then-attend in one grid pass (fused decode).
-
-    Identical online-softmax body to ``_kernel``, except that when this
-    grid cell holds the page the step's new token writes into
-    (j == pos[b] // page), the new K/V row is spliced into the VMEM copy
-    BEFORE attending, and the updated page is written back through the
-    aliased page-pool output.  Cells that do not own the write route
-    their (unchanged) page copy to the scrap page — see
-    ``fused_decode_attention``."""
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    off = pos_ref[b] % page
-    k = k_ref[0]                                       # (page, KV, D)
-    v = v_ref[0]
-    sel = (jax.lax.broadcasted_iota(jnp.int32, k.shape, 0) == off) \
-        & (j == pos_ref[b] // page)
-    k = jnp.where(sel, kn_ref[0][None].astype(k.dtype), k)
-    v = jnp.where(sel, vn_ref[0][None].astype(v.dtype), v)
-    ko_ref[0] = k
-    vo_ref[0] = v
-
-    q = q_ref[0].astype(jnp.float32)                   # (H, D)
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    H, D = q.shape
-    KV = kf.shape[1]
-    qg = q.reshape(KV, G, D)
-
-    s = jax.lax.dot_general(
-        qg, kf, (((2,), (2,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32) * scale     # (KV, G, page)
-    pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (KV, G, page), 2)
-    live = pos < ctx_ref[b]
-    s = jnp.where(live, s, NEG_INF)
+        q, kf, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale     # (H, L*KV)
+    c = jax.lax.broadcasted_iota(jnp.int32, (1, L * KV), 1)
+    r = jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0)
+    own = (c % KV == r // (H // KV)) & (t0 + c // KV < ctx)
+    s = jnp.where(own, s, NEG_INF)
 
     m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2))
-    p = jnp.exp(s - m_new[..., None])
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=2)
+    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
     pv = jax.lax.dot_general(
-        p, vf, (((2,), (0,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32)
-    acc_scr[...] = acc_scr[...] * corr[..., None] + pv
+        p, vf, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)             # (H, D)
+    acc_scr[...] = acc_scr[...] * corr + pv
     m_scr[...] = m_new
+
+
+def _softmax_out(l_scr, acc_scr):
+    """The attention output (H, D) from the final softmax state."""
+    return acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+
+
+def _kernel(tables_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
+            m_scr, l_scr, acc_scr, *, scale: float, page: int, npages: int):
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        _softmax_init(m_scr, l_scr, acc_scr)
+
+    _block_update(q_ref[0].astype(jnp.float32), k_ref[0], v_ref[0],
+                  j * page, ctx_ref[b], m_scr, l_scr, acc_scr, scale=scale)
 
     @pl.when(j == npages - 1)
     def _finish():
-        out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)[..., None]
-        o_ref[0] = out.reshape(H, D).astype(o_ref.dtype)
+        o_ref[0] = _softmax_out(l_scr, acc_scr).astype(o_ref.dtype)
+
+
+# Keys per block of the fused decode walk.  On a v5e at Yi-34B widths
+# (8 lanes of 1-2.5k tokens) a call took 0.105 ms at 128 keys, 0.089 ms
+# at 256 and 0.086 ms at 512: 256 keeps most of the gain while a
+# lane's partial last block wastes at most 255 keys of compute.
+_BLOCK_TOKENS = 256
+_LANES = 128
+
+
+def _pages_per_block(page: int, n_max: int) -> int:
+    """Table entries per block of the fused decode walk (shapes alone)."""
+    return min(n_max, max(1, _BLOCK_TOKENS // page))
+
+
+def _fused_dma_kernel(tab_ref, pos_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm,
+                      o_ref, k_out, v_out, kbuf, vbuf, sems, row_sems,
+                      slot_ref, m_scr, l_scr, acc_scr, *, scale: float,
+                      page: int, ppb: int):
+    """Append-then-attend for lane b = program_id(0) over its live pages.
+
+    The pool stays in HBM.  Lane b's live table entries
+    (0 .. pos[b] // page) are fetched ``ppb`` at a time by DMA into one
+    of two VMEM slots, block i+1 (or the next lane's first block) while
+    block i computes; each block is one online-softmax update over its
+    ``ppb * page`` keys.  The new K/V row is spliced into the VMEM copy
+    of the block that holds ``pos[b]`` before attending, and that one row
+    is written to its page in HBM; no page is written back."""
+    b = pl.program_id(0)
+    lanes = pl.num_programs(0)
+    bk = ppb * page
+    pos = pos_ref[b]
+    n_blocks = (pos // page) // ppb + 1
+
+    def block_dma(lane, i, slot, op):
+        """Start (or wait for) the page copies of lane's block i into
+        ``slot``: only the block's entries before the lane's live-page
+        count, so no entry past the context (which ends inside the
+        table) is ever read."""
+        n_live = pos_ref[lane] // page + 1
+
+        def page_dma(j, carry):
+            pid = tab_ref[lane, i * ppb + j]
+            for kv, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                op(pltpu.make_async_copy(
+                    hbm.at[pid], buf.at[slot, pl.ds(j * page, page)],
+                    sems.at[kv, slot]))
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(ppb, n_live - i * ppb), page_dma, 0)
+
+    def start(lane, i, slot):
+        block_dma(lane, i, slot, lambda cp: cp.start())
+
+    def wait(lane, i, slot):
+        block_dma(lane, i, slot, lambda cp: cp.wait())
+
+    @pl.when(b == 0)
+    def _first():
+        # a last block's entries past the live pages are never fetched:
+        # their rows hold zeros or an earlier block's finite values
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        slot_ref[0] = 0
+        start(0, 0, 0)
+
+    # the append: this one row goes to its page; nothing else is written
+    dst = tab_ref[b, pos // page]
+    rows = [pltpu.make_async_copy(src.at[0], hbm.at[dst, pos % page],
+                                  row_sems.at[kv])
+            for kv, (src, hbm) in enumerate(((kn_ref, k_out),
+                                             (vn_ref, v_out)))]
+    for cp in rows:
+        cp.start()
+
+    _softmax_init(m_scr, l_scr, acc_scr)
+    q = q_ref[0].astype(jnp.float32)
+
+    def body(i, slot):
+        nxt = 1 - slot
+
+        @pl.when(i + 1 < n_blocks)
+        def _prefetch_own():
+            start(b, i + 1, nxt)
+
+        @pl.when((i + 1 == n_blocks) & (b + 1 < lanes))
+        def _prefetch_next_lane():
+            start(b + 1, 0, nxt)
+
+        wait(b, i, slot)
+
+        @pl.when(i == n_blocks - 1)
+        def _splice():
+            kbuf[slot, pos - i * bk] = kn_ref[0]
+            vbuf[slot, pos - i * bk] = vn_ref[0]
+
+        _block_update(q, kbuf[slot], vbuf[slot], i * bk, pos + 1,
+                      m_scr, l_scr, acc_scr, scale=scale)
+        return nxt
+
+    slot_ref[0] = jax.lax.fori_loop(0, n_blocks, body, slot_ref[0])
+    o_ref[0] = _softmax_out(l_scr, acc_scr).astype(o_ref.dtype)
+    for cp in rows:
+        cp.wait()
+
+
+def _fused_window_kernel(tab_ref, pos_ref, q_ref, kn_ref, vn_ref, k_ref,
+                         v_ref, o_ref, ko_ref, vo_ref, m_scr, l_scr,
+                         acc_scr, *, scale: float, page: int):
+    """The same walk one page per grid step (j = program_id(1)), fetched
+    by the grid's own page windows.  Steps past the lane's last live page
+    keep its window (no fetch) and compute nothing; the appended row is
+    the (1, 1, KV, D) output window at (table[b, pos // page], pos %
+    page)."""
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    pos = pos_ref[b]
+    last = pos // page
+
+    @pl.when(j == 0)
+    def _init():
+        _softmax_init(m_scr, l_scr, acc_scr)
+        ko_ref[0, 0] = kn_ref[0]
+        vo_ref[0, 0] = vn_ref[0]
+
+    @pl.when(j <= last)
+    def _attend():
+        k, v = k_ref[0], v_ref[0]                         # (page, KV, D)
+        sel = jax.lax.broadcasted_iota(jnp.int32, k.shape, 0) == pos - j * page
+        k = jnp.where(sel, kn_ref[0][None], k)
+        v = jnp.where(sel, vn_ref[0][None], v)
+        _block_update(q_ref[0].astype(jnp.float32), k, v, j * page, pos + 1,
+                      m_scr, l_scr, acc_scr, scale=scale)
+
+    @pl.when(j == last)
+    def _finish():
+        o_ref[0] = _softmax_out(l_scr, acc_scr).astype(o_ref.dtype)
 
 
 def fused_decode_attention(q, k_new, v_new, k_pages, v_pages, block_tables,
                            positions, *, scale=None, interpret: bool = False):
     """Fused decode step: write each sequence's new KV entry into its page
-    and attend over it in the same grid pass (one dispatch instead of the
-    ``paged_kv_append_batch`` + ``paged_attention`` pair).
+    and attend over its live pages in one call (one dispatch instead of
+    the ``paged_kv_append_batch`` + ``paged_attention`` pair).
 
     q: (B, H, D); k_new/v_new: (B, KV, D) this step's entries; positions:
     (B,) the slot each entry occupies (context length BEFORE the token, so
-    ctx = positions + 1 is attended).  The page pool is passed through as
-    an aliased input/output: the kernel writes every visited page block
-    back, but only the cell owning the write position routes to its real
-    page — all other cells (and padded/finished lanes, whose tables are
-    all-scrap already) land on the scrap page (pool index P-1), which by
-    construction never appears in a live block table.  Returns
-    (out (B, H, D), k_pages, v_pages)."""
+    ctx = positions + 1 is attended).  The page pool is aliased input to
+    output.  Each lane visits only its live table entries
+    (``0 .. positions[b] // page``): no page past its context is read, and
+    the append writes the one (KV, D) row at
+    ``k_pages[table[b, pos // page], pos % page]`` (and the same for V),
+    spliced into the attended copy first.  Padded and retired lanes carry
+    all-scrap tables, so theirs lands on the scrap page (pool index P-1),
+    which by construction never appears in a live block table.  KV heads
+    come from the pool's shape, so a tensor-parallel shard runs it on its
+    own head slice.  Returns (out (B, H, D), k_pages, v_pages).
+
+    Two lowerings of the one walk, chosen by the head width:
+
+    - D a multiple of 128 lanes: one grid step per lane; the pool stays in
+      HBM and the kernel DMAs the live entries ``_pages_per_block(page,
+      n_max)`` at a time (256 keys, or one page when pages are larger),
+      double-buffered across blocks and lanes, one online-softmax update
+      per block.
+    - narrower heads: XLA pads the pool's minor dim to the 128-lane tile,
+      and Mosaic cannot slice such an HBM ref for a DMA, so the grid's own
+      windows fetch one page per step over a ``(B, n_max)`` grid; steps
+      past a lane's live pages keep its last window and skip the math.
+    """
     B, H, D = q.shape
     P, page, KV, _ = k_pages.shape
     n_max = block_tables.shape[1]
-    G = H // KV
     scale = scale or D ** -0.5
-    ctx_lens = (positions + 1).astype(jnp.int32)
-
-    kernel = functools.partial(_fused_kernel, scale=scale, page=page,
-                               npages=n_max, G=G)
-
-    def kv_out_map(b, j, tab, ctx, pos):
-        # the write-back page: real page at the write cell, scrap elsewhere
-        return (jnp.where(j == pos[b] // page, tab[b, j], P - 1), 0, 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, n_max),
-        in_specs=[
-            pl.BlockSpec((1, H, D),
-                         lambda b, j, tab, ctx, pos: (b, 0, 0)),
-            pl.BlockSpec((1, KV, D),
-                         lambda b, j, tab, ctx, pos: (b, 0, 0)),
-            pl.BlockSpec((1, KV, D),
-                         lambda b, j, tab, ctx, pos: (b, 0, 0)),
-            pl.BlockSpec((1, page, KV, D),
-                         lambda b, j, tab, ctx, pos: (tab[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, page, KV, D),
-                         lambda b, j, tab, ctx, pos: (tab[b, j], 0, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, H, D),
-                         lambda b, j, tab, ctx, pos: (b, 0, 0)),
-            pl.BlockSpec((1, page, KV, D), kv_out_map),
-            pl.BlockSpec((1, page, KV, D), kv_out_map),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((KV, G), jnp.float32),
-            pltpu.VMEM((KV, G), jnp.float32),
-            pltpu.VMEM((KV, G, D), jnp.float32),
-        ],
-    )
-    # aliases index the flattened pallas_call operands INCLUDING the three
-    # scalar-prefetch args: k_pages is operand 6, v_pages operand 7
+    args = (block_tables, positions.astype(jnp.int32), q,
+            k_new.astype(k_pages.dtype), v_new.astype(v_pages.dtype),
+            k_pages, v_pages)
+    out_shape = [jax.ShapeDtypeStruct((B, H, D), q.dtype),
+                 jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
+                 jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)]
+    softmax_state = [pltpu.VMEM((H, 1), jnp.float32),
+                     pltpu.VMEM((H, 1), jnp.float32),
+                     pltpu.VMEM((H, D), jnp.float32)]
+    if D % _LANES == 0:
+        ppb = _pages_per_block(page, n_max)
+        kernel = functools.partial(_fused_dma_kernel, scale=scale, page=page,
+                                   ppb=ppb)
+        lane = lambda b, tab, pos: (b, 0, 0)
+        hbm = pl.BlockSpec(memory_space=pl.ANY)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((1, H, D), lane),
+                      pl.BlockSpec((1, KV, D), lane),
+                      pl.BlockSpec((1, KV, D), lane), hbm, hbm],
+            out_specs=[pl.BlockSpec((1, H, D), lane), hbm, hbm],
+            scratch_shapes=[
+                pltpu.VMEM((2, ppb * page, KV, D), k_pages.dtype),
+                pltpu.VMEM((2, ppb * page, KV, D), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32)] + softmax_state,
+        )
+    else:
+        kernel = functools.partial(_fused_window_kernel, scale=scale,
+                                   page=page)
+        lane = lambda b, j, tab, pos: (b, 0, 0)
+        live_page = lambda b, j, tab, pos: (
+            tab[b, jnp.minimum(j, pos[b] // page)], 0, 0, 0)
+        row = lambda b, j, tab, pos: (
+            tab[b, pos[b] // page], pos[b] % page, 0, 0)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, n_max),
+            in_specs=[pl.BlockSpec((1, H, D), lane),
+                      pl.BlockSpec((1, KV, D), lane),
+                      pl.BlockSpec((1, KV, D), lane),
+                      pl.BlockSpec((1, page, KV, D), live_page),
+                      pl.BlockSpec((1, page, KV, D), live_page)],
+            out_specs=[pl.BlockSpec((1, H, D), lane),
+                       pl.BlockSpec((1, 1, KV, D), row),
+                       pl.BlockSpec((1, 1, KV, D), row)],
+            scratch_shapes=softmax_state,
+        )
+    # aliases index the flattened pallas_call operands INCLUDING the two
+    # scalar-prefetch args: k_pages is operand 5, v_pages operand 6
     return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, H, D), q.dtype),
-                   jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-                   jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
-        input_output_aliases={6: 1, 7: 2},
+        kernel, grid_spec=grid_spec, out_shape=out_shape,
+        input_output_aliases={5: 1, 6: 2},
         interpret=interpret, name="fused_decode_attention",
-    )(block_tables, ctx_lens, positions.astype(jnp.int32),
-      q, k_new, v_new, k_pages, v_pages)
+    )(*args)
 
 
 def _verify_kernel(tables_ref, pos0_ref, width_ref, q_ref, kn_ref, vn_ref,
                    k_ref, v_ref, o_ref, ko_ref, vo_ref,
                    m_scr, l_scr, acc_scr, *, scale: float, page: int,
-                   npages: int, G: int, W: int):
+                   npages: int, W: int):
     """Speculative verification: W query rows per lane in one grid pass.
 
     Window row s holds the lane's query at position pos0[b]+s (row 0 the
@@ -227,19 +356,20 @@ def _verify_kernel(tables_ref, pos0_ref, width_ref, q_ref, kn_ref, vn_ref,
     never attended because of the per-row causal mask), then each row
     attends under its own context length pos0+s+1.
 
-    The per-row online-softmax bodies are UNROLLED python loops so every
-    row's dot_general shapes match ``_kernel`` exactly — that makes each
-    verified position's attention output bitwise identical to the
-    sequential single-token decode it replaces, which is what lets
-    spec-on token streams be byte-equal to spec-off (DESIGN.md §11)."""
+    The per-row online-softmax bodies are UNROLLED python loops over
+    ``_block_update`` on one page, which is also each step of the fused
+    decode kernel's page-window lowering (heads under 128 lanes): there a
+    verified position repeats the decode it replaces.  The DMA lowering
+    (128-lane heads) takes the same update over 256-key blocks, so a
+    verified position can differ in the last bits (``chip_smoke.py``
+    measures both); interpret mode chains the decode kernel instead (see
+    ``fused_verify_attention``)."""
     b = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _softmax_init(m_scr, l_scr, acc_scr)
 
     p0 = pos0_ref[b]
     width = width_ref[b]
@@ -254,37 +384,16 @@ def _verify_kernel(tables_ref, pos0_ref, width_ref, q_ref, kn_ref, vn_ref,
     ko_ref[0] = k
     vo_ref[0] = v
 
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    KV = kf.shape[1]
     for s in range(W):
-        q = q_ref[0, s].astype(jnp.float32)            # (H, D)
-        qg = q.reshape(KV, G, q.shape[-1])
-        sc = jax.lax.dot_general(
-            qg, kf, (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32) * scale  # (KV, G, page)
-        pos = j * page + jax.lax.broadcasted_iota(
-            jnp.int32, (KV, G, page), 2)
-        live = pos < p0 + s + 1
-        sc = jnp.where(live, sc, NEG_INF)
-
-        m_prev = m_scr[s]                               # (KV, G)
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=2))
-        p = jnp.exp(sc - m_new[..., None])
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[s] = l_scr[s] * corr + jnp.sum(p, axis=2)
-        pv = jax.lax.dot_general(
-            p, vf, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        acc_scr[s] = acc_scr[s] * corr[..., None] + pv
-        m_scr[s] = m_new
+        _block_update(q_ref[0, s].astype(jnp.float32), k, v, j * page,
+                      p0 + s + 1, m_scr.at[s], l_scr.at[s], acc_scr.at[s],
+                      scale=scale)
 
     @pl.when(j == npages - 1)
     def _finish():
-        H, D = o_ref.shape[2], o_ref.shape[3]
         for s in range(W):
-            out = acc_scr[s] / jnp.maximum(l_scr[s], 1e-30)[..., None]
-            o_ref[0, s] = out.reshape(H, D).astype(o_ref.dtype)
+            o_ref[0, s] = _softmax_out(l_scr.at[s], acc_scr.at[s]).astype(
+                o_ref.dtype)
 
 
 def fused_verify_attention(q, k_new, v_new, k_pages, v_pages, block_tables,
@@ -292,7 +401,7 @@ def fused_verify_attention(q, k_new, v_new, k_pages, v_pages, block_tables,
                            interpret: bool = False):
     """Batched speculative verification: append + attend W window rows per
     lane in one device call (the multi-token generalization of
-    ``fused_decode_attention``; W=1 degenerates to it exactly).
+    ``fused_decode_attention``; W=1 attends as it does).
 
     q: (B, W, H, D); k_new/v_new: (B, W, KV, D) the window rows' entries;
     pos0: (B,) the slot of row 0 (= context length before the window);
@@ -303,8 +412,8 @@ def fused_verify_attention(q, k_new, v_new, k_pages, v_pages, block_tables,
     Two lowerings, same contract:
 
     - real TPU: ``_verify_multirow``, a single grid pass scoring all W
-      rows per lane against each page block while it is resident in VMEM
-      (one pool read for the whole window).
+      rows per lane against each page while it is resident in VMEM (one
+      pool read for the whole window).
     - interpret mode (CPU CI): W chained ``fused_decode_attention`` calls
       through the aliased page pool.  XLA's CPU fusion re-tiles the
       multi-row kernel's unrolled reductions into a different f32
@@ -354,11 +463,10 @@ def _verify_multirow(q, k_new, v_new, k_pages, v_pages, block_tables,
     B, W, H, D = q.shape
     P, page, KV, _ = k_pages.shape
     n_max = block_tables.shape[1]
-    G = H // KV
     scale = scale or D ** -0.5
 
     kernel = functools.partial(_verify_kernel, scale=scale, page=page,
-                               npages=n_max, G=G, W=W)
+                               npages=n_max, W=W)
 
     def kv_out_map(b, j, tab, pos0, width):
         first = pos0[b] // page
@@ -388,9 +496,9 @@ def _verify_multirow(q, k_new, v_new, k_pages, v_pages, block_tables,
             pl.BlockSpec((1, page, KV, D), kv_out_map),
         ],
         scratch_shapes=[
-            pltpu.VMEM((W, KV, G), jnp.float32),
-            pltpu.VMEM((W, KV, G), jnp.float32),
-            pltpu.VMEM((W, KV, G, D), jnp.float32),
+            pltpu.VMEM((W, H, 1), jnp.float32),
+            pltpu.VMEM((W, H, 1), jnp.float32),
+            pltpu.VMEM((W, H, D), jnp.float32),
         ],
     )
     # aliases index the flattened operands INCLUDING the three
@@ -466,11 +574,10 @@ def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
     B, H, D = q.shape
     P, page, KV, _ = k_pages.shape
     n_max = block_tables.shape[1]
-    G = H // KV
     scale = scale or D ** -0.5
 
     kernel = functools.partial(_kernel, scale=scale, page=page,
-                               npages=n_max, G=G)
+                               npages=n_max)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, n_max),
@@ -483,9 +590,9 @@ def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
         ],
         out_specs=pl.BlockSpec((1, H, D), lambda b, j, tab, ctx: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((KV, G), jnp.float32),
-            pltpu.VMEM((KV, G), jnp.float32),
-            pltpu.VMEM((KV, G, D), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, D), jnp.float32),
         ],
     )
     return pl.pallas_call(

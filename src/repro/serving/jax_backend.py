@@ -218,7 +218,8 @@ class PagedJaxBackend(Backend):
         self.obs = obs
         self._m_pages = obs.counter(
             "jax_pages_touched_total",
-            "block-table pages referenced by dispatches")
+            "block-table pages referenced by dispatches (decode: the "
+            "fused kernel reads a lane's live ones per layer, no other)")
         self._m_compile = obs.counter(
             "jax_compiles_total",
             "XLA compiles and persistent-cache loads the dispatches "

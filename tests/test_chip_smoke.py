@@ -47,6 +47,13 @@ def test_verify_kernel_lowerings_agree_at_tinyllama_head_widths(cs):
     assert pages_equal
 
 
+def test_verify_kernel_lowerings_agree_at_wide_head_widths(cs):
+    err, pages_equal = cs.verify_kernel_error(get_config(cs.WIDE_HEADS), 0,
+                                              interpret=True)
+    assert err <= cs.KERNEL_TOL, err
+    assert pages_equal
+
+
 @pytest.fixture(scope="module")
 def served(cs):
     """A reduced two-layer tinyllama in bf16 served through the smoke's

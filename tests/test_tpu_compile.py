@@ -6,7 +6,8 @@ described, not attached.  That catches what interpret mode cannot: block
 shapes the Mosaic lowering refuses, VMEM overruns, and kernels whose
 lowering path raises before it ever reaches the compiler.  Shapes are
 tinyllama-1.1b's published widths in bf16 with the backend's default
-16-token pages.
+16-token pages; the fused decode kernel also compiles at the benchmark
+cell's Yi-34B attention widths, where it DMAs the pool itself.
 
 The topology is described inside a module fixture only: loading the TPU
 library takes a process-wide lock, so it must happen in the one worker
@@ -64,15 +65,16 @@ def _sds(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _kernel_args(sharding, cfg, rows=None):
+def _kernel_args(sharding, cfg, rows=None, *, page=PAGE, max_len=MAX_LEN,
+                 pool=POOL):
     H, KV, D = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     lead = (B,) if rows is None else (B, rows)
     bf, i32 = jnp.bfloat16, jnp.int32
     return dict(
         q=_sds(sharding, lead + (H, D), bf),
         kv_new=_sds(sharding, lead + (KV, D), bf),
-        pages=_sds(sharding, (POOL, PAGE, KV, D), bf),
-        tables=_sds(sharding, (B, MAX_LEN // PAGE), i32),
+        pages=_sds(sharding, (pool, page, KV, D), bf),
+        tables=_sds(sharding, (B, max_len // page), i32),
         vec=_sds(sharding, (B,), i32))
 
 
@@ -82,6 +84,22 @@ def _compiled_text(fn, *args):
 
 def test_fused_decode_kernel_compiles_for_v5e(one_chip, cfg):
     a = _kernel_args(one_chip, cfg)
+    txt = _compiled_text(
+        functools.partial(fused_decode_attention, interpret=False),
+        a["q"], a["kv_new"], a["kv_new"], a["pages"], a["pages"],
+        a["tables"], a["vec"])
+    assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("page", [16, 128])
+def test_fused_decode_kernel_compiles_for_v5e_at_yi34b_widths(one_chip,
+                                                              page):
+    """The benchmark cell's attention shape (H 56, KV 8 so G = 7, D 128,
+    bf16, 4096-token tables: n_max 256 at 16-token pages), where the
+    kernel DMAs live pages from the HBM pool itself: 8-page blocks at
+    16-token pages, one page a block at 128."""
+    a = _kernel_args(one_chip, get_config("yi-34b"), page=page,
+                     max_len=4096, pool=36864 // page + 1)
     txt = _compiled_text(
         functools.partial(fused_decode_attention, interpret=False),
         a["q"], a["kv_new"], a["kv_new"], a["pages"], a["pages"],
