@@ -224,17 +224,17 @@ def teacher_forced_logits(be, items, k: int = CHECKED_DECODES):
         C = _bucket(len(prompt))
         toks = np.zeros((1, C), np.int32)
         toks[0, :len(prompt)] = prompt
-        pages = be._prefill(be.params, pages, jnp.asarray(toks),
-                            jnp.int32(0), jnp.asarray(tabs[i]),
-                            jnp.int32(len(prompt)))
+        pages, _ = be._prefill(be.params, pages, jnp.asarray(toks),
+                               jnp.int32(0), jnp.asarray(tabs[i]),
+                               jnp.int32(len(prompt)))
     out = []
     for s in range(k + 1):
         toks = np.array([[prompt[-1] if s == 0 else served[s - 1]]
                          for prompt, served in items], np.int32)
         pos = np.array([len(prompt) - 1 + s for prompt, _ in items],
                        np.int32)
-        logits, pages = be._decode(be.params, pages, jnp.asarray(toks),
-                                   jnp.asarray(pos), jnp.asarray(tabs))
+        logits, pages, _ = be._decode(be.params, pages, jnp.asarray(toks),
+                                      jnp.asarray(pos), jnp.asarray(tabs))
         out.append(np.asarray(logits, np.float32))
     be.pages = pages
     return np.stack(out, axis=1)

@@ -24,8 +24,12 @@ def reduced_config(name: str) -> ModelConfig:
     """A tiny same-family variant of an assigned arch for CPU smoke tests.
 
     Keeps: layer-pattern family (MLA vs GQA vs mamba vs xLSTM, MoE-ness,
-    frontend stub, positional scheme).  Shrinks: width, layer count, expert
-    count, vocab.  Runs one forward/train step on a single CPU device.
+    frontend stub, positional scheme with its rope layout and YaRN, router
+    normalisation), leading dense layers.  Shrinks: width, layer count,
+    expert count, vocab.  Latent-attention MoE models (the DeepSeek family,
+    served as one expert-parallel device's share) keep a share: 4 of 8
+    routed experts held.  Runs one forward/train step on a single CPU
+    device.
     """
     cfg = get_config(name)
     pat = cfg.unit_pattern
@@ -46,6 +50,8 @@ def reduced_config(name: str) -> ModelConfig:
         changes.update(num_experts=4, top_k=2,
                        num_shared_experts=min(cfg.num_shared_experts, 1),
                        d_ff_expert=64)
+        if cfg.kv_lora_rank:
+            changes.update(num_experts=8, experts_held=4, experts_first=0)
     if cfg.kv_lora_rank:
         changes.update(kv_lora_rank=32,
                        q_lora_rank=48 if cfg.q_lora_rank else 0,

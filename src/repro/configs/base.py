@@ -20,9 +20,15 @@ Each pattern element is ``(mixer, ffn)``:
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Tuple
 
 Layer = Tuple[str, str]  # (mixer, ffn)
+
+
+def yarn_get_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor (HF ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
 def _round_up(x: int, m: int) -> int:
@@ -48,6 +54,16 @@ class ModelConfig:
     head_dim: int = 0                # 0 -> d_model // num_heads
     rope_theta: float = 10000.0
     positional: str = "rope"         # rope | sinusoidal | none
+    # DeepSeek's rope layout: pairs (x[2i], x[2i+1]) rotate together
+    rope_interleaved: bool = False
+    # YaRN context extension (HF DeepseekV2YarnRotaryEmbedding); factor 0
+    # keeps plain rope
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # MLA (DeepSeek-style multi-head latent attention)
     kv_lora_rank: int = 0            # 0 -> MLA disabled for "mla" mixers
@@ -62,6 +78,13 @@ class ModelConfig:
     top_k: int = 0
     d_ff_expert: int = 0
     capacity_factor: float = 1.25
+    norm_topk_prob: bool = True      # renormalise the top-k router weights
+    routed_scaling_factor: float = 1.0
+    # expert parallelism's share: this device holds routed experts
+    # experts_first .. experts_first + experts_held - 1 of the num_experts
+    # the router scores (0 -> all of them)
+    experts_held: int = 0
+    experts_first: int = 0
 
     # Mamba (S6)
     mamba_d_state: int = 16
@@ -85,7 +108,23 @@ class ModelConfig:
     # Sub-quadratic? (drives long_500k applicability)
     subquadratic: bool = False
 
+    def __post_init__(self):
+        # configurations built from JSON carry lists where tuples belong
+        for f in ("unit_pattern", "prefix_pattern"):
+            v = getattr(self, f)
+            object.__setattr__(self, f, tuple(tuple(x) for x in v))
+
     # ------------------------------------------------------------------
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def mla_softmax_scale(self) -> float:
+        """(nope + rope)^-1/2, times YaRN's mscale_all_dim factor squared."""
+        m = yarn_get_mscale(self.yarn_factor, self.yarn_mscale_all_dim)
+        return (self.qk_nope_dim + self.qk_rope_dim) ** -0.5 * m * m
+
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.num_heads
@@ -138,7 +177,8 @@ class ModelConfig:
             n += self._mixer_params(mixer)
             if ffn == "moe":
                 per_exp = 3 * self.d_model * self.d_ff_expert
-                n += (self.top_k + self.num_shared_experts) * per_exp
+                routed = self.top_k * self.held_experts / self.num_experts
+                n += int((routed + self.num_shared_experts) * per_exp)
                 n += self.d_model * self.num_experts   # router
             else:
                 n += self._ffn_params(ffn)
@@ -185,7 +225,7 @@ class ModelConfig:
             return 3 * d * self.d_ff
         if ffn == "moe":
             n = self.d_model * self.num_experts
-            n += self.num_experts * 3 * d * self.d_ff_expert
+            n += self.held_experts * 3 * d * self.d_ff_expert
             n += self.num_shared_experts * 3 * d * self.d_ff_expert
             return n
         if ffn == "none":

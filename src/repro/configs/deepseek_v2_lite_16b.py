@@ -1,8 +1,14 @@
-"""deepseek-v2-lite-16b [moe] — arXiv:2405.04434; hf-verified.
+"""deepseek-v2-lite-16b [moe] — arXiv:2405.04434; hf-verified against
+https://huggingface.co/deepseek-ai/DeepSeek-V2-Lite/blob/main/config.json.
 
-27L d_model=2048 16H d_ff_expert=1408 vocab=102400, MoE 64 routed top-6 +
-2 shared, MLA kv_lora=512.  Per the HF config the first layer is dense
-(``first_k_dense_replace=1``) with d_ff=10944.
+27L d_model=2048 16H vocab=102400.  MLA with kv_lora_rank 512, no q LoRA,
+nope/rope/v head dims 128/64/128, DeepSeek's interleaved rope with YaRN
+(factor 40 over 4096 original positions, beta 32/1, mscale 0.707 both ways,
+so the softmax scale is 192^-0.5 * (0.1 * 0.707 * ln 40 + 1)^2).  The first
+layer is dense (``first_k_dense_replace=1``, d_ff=10944); the other 26 have
+64 routed experts of width 1408, top-6 from a softmax router with greedy
+selection and no renormalisation (``norm_topk_prob`` false,
+``routed_scaling_factor`` 1), plus 2 shared experts.  RMSNorm eps 1e-6.
 """
 
 from repro.configs.base import ModelConfig, register
@@ -21,6 +27,14 @@ def deepseek_v2_lite_16b() -> ModelConfig:
         vocab_size=102400,
         prefix_pattern=(("mla", "mlp"),),
         unit_pattern=(("mla", "moe"),),
+        rope_theta=10000.0,
+        rope_interleaved=True,
+        yarn_factor=40.0,
+        yarn_original_max_pos=4096,
+        yarn_beta_fast=32.0,
+        yarn_beta_slow=1.0,
+        yarn_mscale=0.707,
+        yarn_mscale_all_dim=0.707,
         kv_lora_rank=512,
         q_lora_rank=0,                 # lite: direct q projection
         qk_nope_dim=128,
@@ -30,4 +44,7 @@ def deepseek_v2_lite_16b() -> ModelConfig:
         num_shared_experts=2,
         top_k=6,
         d_ff_expert=1408,
+        norm_topk_prob=False,
+        routed_scaling_factor=1.0,
+        norm_eps=1e-6,
     )

@@ -76,7 +76,12 @@ def _block_update(q, k, v, t0, ctx, m_scr, l_scr, acc_scr, *,
     r = jax.lax.broadcasted_iota(jnp.int32, (H, 1), 0)
     own = (c % KV == r // (H // KV)) & (t0 + c // KV < ctx)
     s = jnp.where(own, s, NEG_INF)
+    _softmax_update(s, vf, m_scr, l_scr, acc_scr)
 
+
+def _softmax_update(s, vf, m_scr, l_scr, acc_scr):
+    """One online-softmax step: scores s (H, N) f32, masked to NEG_INF
+    where a key does not count, against values vf (N, D) f32."""
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     p = jnp.exp(s - m_new)
@@ -124,56 +129,100 @@ def _pages_per_block(page: int, n_max: int) -> int:
     return min(n_max, max(1, _BLOCK_TOKENS // page))
 
 
-def _fused_dma_kernel(tab_ref, pos_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm,
-                      o_ref, k_out, v_out, kbuf, vbuf, sems, row_sems,
-                      slot_ref, m_scr, l_scr, acc_scr, *, scale: float,
-                      page: int, ppb: int):
-    """Append-then-attend for lane b = program_id(0) over its live pages.
+class _LiveWalk:
+    """The live-page walk that both fused decode kernels share, for lane
+    b = program_id(0) of a one-step-per-lane grid.
 
-    The pool stays in HBM.  Lane b's live table entries
-    (0 .. pos[b] // page) are fetched ``ppb`` at a time by DMA into one
-    of two VMEM slots, block i+1 (or the next lane's first block) while
-    block i computes; each block is one online-softmax update over its
-    ``ppb * page`` keys.  The new K/V row is spliced into the VMEM copy
-    of the block that holds ``pos[b]`` before attending, and that one row
-    is written to its page in HBM; no page is written back."""
-    b = pl.program_id(0)
-    lanes = pl.num_programs(0)
-    bk = ppb * page
-    pos = pos_ref[b]
-    n_blocks = (pos // page) // ppb + 1
+    Each pool stays in HBM.  Lane b's live table entries
+    (0 .. pos[b] // page) are fetched ``ppb`` at a time by DMA, from every
+    pool in ``pools`` ((hbm ref, VMEM buffer (2, ppb * page, ...)) pairs),
+    into one of the buffers' two slots: block i+1 (or the next lane's first
+    block) while block i computes.  A last block's entries past the live
+    pages are never fetched: their rows hold zeros or an earlier block's
+    finite values, which the caller masks."""
 
-    def block_dma(lane, i, slot, op):
+    def __init__(self, tab_ref, pos_ref, pools, sems, slot_ref, *,
+                 page: int, ppb: int):
+        self.b = pl.program_id(0)
+        self.lanes = pl.num_programs(0)
+        self.bk = ppb * page
+        self.pos = pos_ref[self.b]
+        self.n_blocks = (self.pos // page) // ppb + 1
+        self.tab_ref, self.pos_ref, self.pools = tab_ref, pos_ref, pools
+        self.sems, self.slot_ref = sems, slot_ref
+        self.page, self.ppb = page, ppb
+
+    def _block_dma(self, lane, i, slot, op):
         """Start (or wait for) the page copies of lane's block i into
         ``slot``: only the block's entries before the lane's live-page
         count, so no entry past the context (which ends inside the
         table) is ever read."""
-        n_live = pos_ref[lane] // page + 1
+        page, ppb = self.page, self.ppb
+        n_live = self.pos_ref[lane] // page + 1
 
         def page_dma(j, carry):
-            pid = tab_ref[lane, i * ppb + j]
-            for kv, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+            pid = self.tab_ref[lane, i * ppb + j]
+            for kv, (hbm, buf) in enumerate(self.pools):
                 op(pltpu.make_async_copy(
                     hbm.at[pid], buf.at[slot, pl.ds(j * page, page)],
-                    sems.at[kv, slot]))
+                    self.sems.at[kv, slot]))
             return carry
 
         jax.lax.fori_loop(0, jnp.minimum(ppb, n_live - i * ppb), page_dma, 0)
 
-    def start(lane, i, slot):
-        block_dma(lane, i, slot, lambda cp: cp.start())
+    def _start(self, lane, i, slot):
+        self._block_dma(lane, i, slot, lambda cp: cp.start())
 
-    def wait(lane, i, slot):
-        block_dma(lane, i, slot, lambda cp: cp.wait())
+    def _wait(self, lane, i, slot):
+        self._block_dma(lane, i, slot, lambda cp: cp.wait())
 
-    @pl.when(b == 0)
-    def _first():
-        # a last block's entries past the live pages are never fetched:
-        # their rows hold zeros or an earlier block's finite values
-        kbuf[...] = jnp.zeros_like(kbuf)
-        vbuf[...] = jnp.zeros_like(vbuf)
-        slot_ref[0] = 0
-        start(0, 0, 0)
+    def first(self):
+        """On the grid's first lane: zero the buffers and start its first
+        block."""
+        @pl.when(self.b == 0)
+        def _first():
+            for _, buf in self.pools:
+                buf[...] = jnp.zeros_like(buf)
+            self.slot_ref[0] = 0
+            self._start(0, 0, 0)
+
+    def run(self, update):
+        """``update(i, slot)`` on each of the lane's blocks once its
+        copies have landed."""
+        b, lanes, n_blocks = self.b, self.lanes, self.n_blocks
+
+        def body(i, slot):
+            nxt = 1 - slot
+
+            @pl.when(i + 1 < n_blocks)
+            def _prefetch_own():
+                self._start(b, i + 1, nxt)
+
+            @pl.when((i + 1 == n_blocks) & (b + 1 < lanes))
+            def _prefetch_next_lane():
+                self._start(b + 1, 0, nxt)
+
+            self._wait(b, i, slot)
+            update(i, slot)
+            return nxt
+
+        self.slot_ref[0] = jax.lax.fori_loop(0, n_blocks, body,
+                                             self.slot_ref[0])
+
+
+def _fused_dma_kernel(tab_ref, pos_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm,
+                      o_ref, k_out, v_out, kbuf, vbuf, sems, row_sems,
+                      slot_ref, m_scr, l_scr, acc_scr, *, scale: float,
+                      page: int, ppb: int):
+    """Append-then-attend for lane b = program_id(0) over its live pages
+    (``_LiveWalk``), one online-softmax update per block of ``ppb * page``
+    keys.  The new K/V row is spliced into the VMEM copy of the block
+    that holds ``pos[b]`` before attending, and that one row is written
+    to its page in HBM; no page is written back."""
+    walk = _LiveWalk(tab_ref, pos_ref, ((k_hbm, kbuf), (v_hbm, vbuf)), sems,
+                     slot_ref, page=page, ppb=ppb)
+    b, bk, pos = walk.b, walk.bk, walk.pos
+    walk.first()
 
     # the append: this one row goes to its page; nothing else is written
     dst = tab_ref[b, pos // page]
@@ -187,29 +236,16 @@ def _fused_dma_kernel(tab_ref, pos_ref, q_ref, kn_ref, vn_ref, k_hbm, v_hbm,
     _softmax_init(m_scr, l_scr, acc_scr)
     q = q_ref[0].astype(jnp.float32)
 
-    def body(i, slot):
-        nxt = 1 - slot
-
-        @pl.when(i + 1 < n_blocks)
-        def _prefetch_own():
-            start(b, i + 1, nxt)
-
-        @pl.when((i + 1 == n_blocks) & (b + 1 < lanes))
-        def _prefetch_next_lane():
-            start(b + 1, 0, nxt)
-
-        wait(b, i, slot)
-
-        @pl.when(i == n_blocks - 1)
+    def update(i, slot):
+        @pl.when(i == walk.n_blocks - 1)
         def _splice():
             kbuf[slot, pos - i * bk] = kn_ref[0]
             vbuf[slot, pos - i * bk] = vn_ref[0]
 
         _block_update(q, kbuf[slot], vbuf[slot], i * bk, pos + 1,
                       m_scr, l_scr, acc_scr, scale=scale)
-        return nxt
 
-    slot_ref[0] = jax.lax.fori_loop(0, n_blocks, body, slot_ref[0])
+    walk.run(update)
     o_ref[0] = _softmax_out(l_scr, acc_scr).astype(o_ref.dtype)
     for cp in rows:
         cp.wait()
@@ -340,6 +376,100 @@ def fused_decode_attention(q, k_new, v_new, k_pages, v_pages, block_tables,
         input_output_aliases={5: 1, 6: 2},
         interpret=interpret, name="fused_decode_attention",
     )(*args)
+
+
+def _mla_dma_kernel(tab_ref, pos_ref, q_ref, rn_ref, lat_hbm, o_ref, lat_out,
+                    buf, sems, page_sem, slot_ref, m_scr, l_scr, acc_scr, *,
+                    scale: float, page: int, ppb: int, v_width: int):
+    """Absorbed MLA decode for lane b = program_id(0): all H heads attend
+    over the lane's latent rows ``[c | k_rope | 0]`` in the walk's blocks,
+    one (H, W) x (W, keys) score matmul and one (H, keys) x (keys, r)
+    value matmul per block (the heads are the matmul's M dimension).  The
+    appended row is spliced into the VMEM copy of its page before the last
+    block attends, and that page is written back: a single bf16 row is
+    narrower than the pool's packed tiling, so no DMA can write it alone."""
+    walk = _LiveWalk(tab_ref, pos_ref, ((lat_hbm, buf),), sems, slot_ref,
+                     page=page, ppb=ppb)
+    b, bk, pos = walk.b, walk.bk, walk.pos
+    walk.first()
+    _softmax_init(m_scr, l_scr, acc_scr)
+    q = q_ref[0]                                          # (H, W) f32
+    last = walk.n_blocks - 1
+    at = pl.ds(pl.multiple_of((pos // page - last * ppb) * page, page), page)
+
+    def update(i, slot):
+        @pl.when(i == last)
+        def _splice():
+            sel = jax.lax.broadcasted_iota(jnp.int32, (page, 1), 0) \
+                == pos % page
+            buf[slot, at] = jnp.where(sel, rn_ref[0], buf[slot, at])
+
+        blk = buf[slot].astype(jnp.float32)               # (bk, W)
+        s = jax.lax.dot_general(
+            q, blk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # (H, bk)
+        t = i * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        s = jnp.where(t <= pos, s, NEG_INF)
+        _softmax_update(s, blk[:, :v_width], m_scr, l_scr, acc_scr)
+
+    walk.run(update)
+    # the block walked last sits in the slot the walk did not end on
+    page_cp = pltpu.make_async_copy(buf.at[1 - slot_ref[0], at],
+                                    lat_out.at[tab_ref[b, pos // page]],
+                                    page_sem.at[0])
+    page_cp.start()
+    o_ref[0] = _softmax_out(l_scr, acc_scr)
+    page_cp.wait()
+
+
+def mla_decode_attention(q, row_new, lat_pages, block_tables, positions, *,
+                         scale: float, v_width: int,
+                         interpret: bool = False):
+    """Fused absorbed MLA decode step over a latent page pool: append each
+    lane's latent row and attend over its live pages in one call.
+
+    q: (B, H, W) float32, per head ``[q_nope W_uk | q_rope | 0]`` (the
+    absorbed query against the latent, then the rope query); row_new:
+    (B, 1, W) this step's rows ``[c | k_rope | 0]`` in the pool's dtype;
+    lat_pages: (P, page, W), W a multiple of 128 lanes so the pool is
+    DMA'd unpadded; positions: (B,) the slot each row occupies (context
+    before the token).  The pool is aliased input to output and only the
+    page holding the appended row is written (retired lanes' land on the
+    scrap page P-1, as in ``fused_decode_attention``).  The walk is ``_LiveWalk``'s:
+    ``_pages_per_block`` pages a block, double-buffered across blocks and
+    lanes.  Returns (softmax-weighted latents (B, H, v_width) float32 —
+    the first ``v_width`` = kv_lora_rank columns of the rows, which the
+    caller maps through W_uv — and the pool)."""
+    B, H, W = q.shape
+    P, page, _ = lat_pages.shape
+    ppb = _pages_per_block(page, block_tables.shape[1])
+    kernel = functools.partial(_mla_dma_kernel, scale=scale, page=page,
+                               ppb=ppb, v_width=v_width)
+    lane = lambda b, tab, pos: (b, 0, 0)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[pl.BlockSpec((1, H, W), lane),
+                  pl.BlockSpec((1, 1, W), lane), hbm],
+        out_specs=[pl.BlockSpec((1, H, v_width), lane), hbm],
+        scratch_shapes=[pltpu.VMEM((2, ppb * page, W), lat_pages.dtype),
+                        pltpu.SemaphoreType.DMA((1, 2)),
+                        pltpu.SemaphoreType.DMA((1,)),
+                        pltpu.SMEM((1,), jnp.int32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, v_width), jnp.float32)],
+    )
+    # lat_pages is operand 4 counting the two scalar-prefetch args
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, H, v_width), jnp.float32),
+                   jax.ShapeDtypeStruct(lat_pages.shape, lat_pages.dtype)],
+        input_output_aliases={4: 1},
+        interpret=interpret, name="mla_decode_attention",
+    )(block_tables, positions.astype(jnp.int32), q.astype(jnp.float32),
+      row_new.astype(lat_pages.dtype), lat_pages)
 
 
 def _verify_kernel(tables_ref, pos0_ref, width_ref, q_ref, kn_ref, vn_ref,
@@ -526,19 +656,35 @@ def paged_kv_append(k_pages, v_pages, k_new, v_new, block_table, start,
     routed to ``scrap_page`` so callers can pad chunks to a few static
     shapes without corrupting live pages.  Returns (k_pages, v_pages).
     """
-    C = k_new.shape[0]
-    page = k_pages.shape[1]
+    page_ids, offs = _append_slots(k_pages, k_new.shape[0], block_table,
+                                   start, n, scrap_page)
+    k_pages = k_pages.at[page_ids, offs].set(k_new.astype(k_pages.dtype))
+    v_pages = v_pages.at[page_ids, offs].set(v_new.astype(v_pages.dtype))
+    return k_pages, v_pages
+
+
+def _append_slots(pages, C, block_table, start, n, scrap_page):
+    """(page, slot) of each of a chunk's C rows; rows past ``n`` go to the
+    scrap page."""
+    page = pages.shape[1]
     idx = start + jnp.arange(C)
     page_ids = block_table[idx // page]
     offs = idx % page
     if n is not None:
         pad = jnp.arange(C) >= n
-        fill = k_pages.shape[0] - 1 if scrap_page is None else scrap_page
+        fill = pages.shape[0] - 1 if scrap_page is None else scrap_page
         page_ids = jnp.where(pad, fill, page_ids)
         offs = jnp.where(pad, 0, offs)
-    k_pages = k_pages.at[page_ids, offs].set(k_new.astype(k_pages.dtype))
-    v_pages = v_pages.at[page_ids, offs].set(v_new.astype(v_pages.dtype))
-    return k_pages, v_pages
+    return page_ids, offs
+
+
+def paged_row_append(pages, rows, block_table, start, n=None,
+                     scrap_page=None):
+    """``paged_kv_append`` for one pool of any row shape: pages
+    (P, page, ...), rows (C, ...)."""
+    page_ids, offs = _append_slots(pages, rows.shape[0], block_table, start,
+                                   n, scrap_page)
+    return pages.at[page_ids, offs].set(rows.astype(pages.dtype))
 
 
 def paged_kv_append_batch(k_pages, v_pages, k_new, v_new, block_tables,
@@ -559,12 +705,12 @@ def paged_kv_append_batch(k_pages, v_pages, k_new, v_new, block_tables,
 
 
 def paged_gather(pages, block_table):
-    """Gather one sequence's pages into a contiguous (n_max*page, KV, D)
-    view — the dense side of the append round-trip (chunked prefill attends
+    """Gather one sequence's pages into a contiguous (n_max*page, ...) view
+    — the dense side of the append round-trip (chunked prefill attends
     over it; positions past the context length must be masked by the
     caller)."""
-    P, page, KV, D = pages.shape
-    return pages[block_table].reshape(block_table.shape[0] * page, KV, D)
+    return pages[block_table].reshape(
+        (block_table.shape[0] * pages.shape[1],) + pages.shape[2:])
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,

@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from repro.models.layers import apply_rope, rope_tables
 
 NEG_INF = -1e30
+# keys a block of the latent prefill's online softmax walks at a time
+PREFILL_KEY_ROWS = 512
 
 
 # ---------------------------------------------------------------------------
@@ -296,23 +298,35 @@ def _mla_q(x, p, cfg):
     return q  # (B,S,H, nope+rope)
 
 
-def mla_apply(x, p, cfg, ctx, mode, cache=None, index=None):
+def _mla_rope(cfg, pos):
+    """cos/sin tables of the rope part at ``pos`` (YaRN where set)."""
+    return rope_tables(pos, cfg.qk_rope_dim, cfg.rope_theta, cfg)
+
+
+def _mla_latents(x, p, cfg, cos, sin):
+    """The per-token rows MLA caches: c = RMSNorm(x W_dkv) (B,S,r) and the
+    roped shared key k_rope = RoPE(x W_kr) (B,S,rope)."""
     from repro.models.layers import rms_norm
+    ckv = rms_norm(x @ p["w_dkv"], p["kv_ln"], cfg.norm_eps)
+    k_rope = apply_rope((x @ p["w_kr"])[:, :, None, :], cos, sin,
+                        cfg.rope_interleaved)[:, :, 0, :]
+    return ckv, k_rope
+
+
+def mla_apply(x, p, cfg, ctx, mode, cache=None, index=None):
     B, S, D = x.shape
     H = cfg.num_heads
     nope, rope_d, vd, r = (cfg.qk_nope_dim, cfg.qk_rope_dim,
                            cfg.v_head_dim, cfg.kv_lora_rank)
-    scale = (nope + rope_d) ** -0.5
+    scale = cfg.mla_softmax_scale
 
     q = _mla_q(x, p, cfg)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    ckv = rms_norm(x @ p["w_dkv"], p["kv_ln"], cfg.norm_eps)      # (B,S,r)
-    k_rope = (x @ p["w_kr"])[:, :, None, :]                       # (B,S,1,rope)
-
     pos = jnp.full((1,), index) if mode == "decode" else jnp.arange(S)
-    cos, sin = rope_tables(pos, rope_d, cfg.rope_theta)
-    q_rope = apply_rope(q_rope, cos, sin)
-    k_rope = apply_rope(k_rope, cos, sin)
+    cos, sin = _mla_rope(cfg, pos)
+    q_rope = apply_rope(q_rope, cos, sin, cfg.rope_interleaved)
+    ckv, k_rope = _mla_latents(x, p, cfg, cos, sin)
+    k_rope = k_rope[:, :, None, :]                                # (B,S,1,rope)
 
     if mode in ("train", "prefill"):
         # Naive path: materialise per-head K/V (compute-friendly at long S).
@@ -353,3 +367,105 @@ def mla_apply(x, p, cfg, ctx, mode, cache=None, index=None):
         new_cache = {"ckv": cc, "kr": ckr}
     out = jnp.einsum("bshv,hvd->bsd", o.astype(x.dtype), p["wo"])
     return out, new_cache
+
+
+# ---------------------------------------------------------------------------
+# MLA over a paged latent pool (DESIGN.md §3): one pool per layer,
+# (pages, page, W) rows [c (r) | k_rope (rope) | 0] with W the row rounded
+# up to whole 128-lane tiles
+# ---------------------------------------------------------------------------
+def mla_row_width(cfg) -> int:
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_dim) // 128) * 128
+
+
+def _mla_rows(ckv, k_rope, width, dtype):
+    """(..., r) and (..., rope) -> (..., width) pool rows."""
+    pad = width - ckv.shape[-1] - k_rope.shape[-1]
+    zeros = jnp.zeros(ckv.shape[:-1] + (pad,), dtype)
+    return jnp.concatenate([ckv.astype(dtype), k_rope.astype(dtype), zeros],
+                           -1)
+
+
+def mla_prefill_paged(x, p, cfg, pages, block_table, start, n):
+    """Chunked prefill of ONE sequence into its latent pages, then causal
+    attention of the chunk over its context with per-head K/V expanded
+    from the latents (k_nope = c W_uk, v = c W_uv, the shared roped key on
+    every head).  x: (1, C, D) with rows at or past ``n`` padding (their
+    rows go to the scrap page).  The context is walked in blocks of about
+    ``PREFILL_KEY_ROWS`` keys with an online softmax, and only the blocks
+    up to the chunk's last real row: time and transient memory follow the
+    context, not the block table's width.  Returns (out, pages)."""
+    from repro.kernels.paged_attention import paged_row_append
+    B, C, D = x.shape
+    H, nope, r = cfg.num_heads, cfg.qk_nope_dim, cfg.kv_lora_rank
+    rope_d = cfg.qk_rope_dim
+    lat = pages["lat"]
+    page = lat.shape[1]
+    pos = start + jnp.arange(C)
+    cos, sin = _mla_rope(cfg, pos)
+    q = _mla_q(x, p, cfg)[0]                                   # (C, H, qk)
+    q_nope = q[..., :nope].astype(jnp.float32)
+    q_rope = apply_rope(q[None, ..., nope:], cos, sin,
+                        cfg.rope_interleaved)[0].astype(jnp.float32)
+    ckv, k_rope = _mla_latents(x, p, cfg, cos, sin)
+    rows = _mla_rows(ckv[0], k_rope[0], lat.shape[-1], lat.dtype)
+    lat = paged_row_append(lat, rows, block_table, start, n=n)
+
+    kb = max(1, PREFILL_KEY_ROWS // page)                    # pages a key block
+    Lk = kb * page
+    last = (start + jnp.maximum(n, 1) - 1) // page   # last live table entry
+
+    def block(j, carry):
+        m, l, acc = carry
+        # a block's entries past the last live one re-read it (masked)
+        ids = block_table[jnp.minimum(j * kb + jnp.arange(kb), last)]
+        ctx_rows = lat[ids].reshape(Lk, lat.shape[-1])
+        c = ctx_rows[:, :r]
+        kr = ctx_rows[:, r:r + rope_d].astype(jnp.float32)
+        k_nope = jnp.einsum("lr,rhn->lhn", c, p["w_uk"]).astype(jnp.float32)
+        v = jnp.einsum("lr,rhv->lhv", c, p["w_uv"]).astype(jnp.float32)
+        s = (jnp.einsum("chn,lhn->hcl", q_nope, k_nope)
+             + jnp.einsum("chp,lp->hcl", q_rope, kr)) * cfg.mla_softmax_scale
+        kpos = j * Lk + jnp.arange(Lk)
+        s = jnp.where((kpos[None, :] <= pos[:, None])[None], s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(-1))
+        w = jnp.exp(s - m_new[..., None])
+        corr = jnp.exp(m - m_new)
+        return (m_new, l * corr + w.sum(-1),
+                acc * corr[..., None] + jnp.einsum("hcl,lhv->hcv", w, v))
+
+    m0 = jnp.full((H, C), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((H, C), jnp.float32)
+    a0 = jnp.zeros((H, C, cfg.v_head_dim), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, last // kb + 1, block, (m0, l0, a0))
+    o = jnp.transpose(acc / l[..., None], (1, 0, 2))[None]    # (1, C, H, v)
+    out = jnp.einsum("bshv,hvd->bsd", o.astype(x.dtype), p["wo"])
+    return out, {"lat": lat}
+
+
+def mla_decode_paged(x, p, cfg, pages, block_tables, positions, *,
+                     interpret=False):
+    """Batched one-token absorbed MLA decode through the fused
+    ``mla_decode_attention`` kernel: per head the query against the latent
+    is q_nope W_uk (W_uk absorbed into the query), the latents' softmax
+    average goes through W_uv after the kernel.  x: (B, 1, D); positions
+    (B,) the slot of the new row.  Returns (out (B, 1, D), pages)."""
+    from repro.kernels.paged_attention import mla_decode_attention
+    nope, r = cfg.qk_nope_dim, cfg.kv_lora_rank
+    lat = pages["lat"]
+    W = lat.shape[-1]
+    cos, sin = _mla_rope(cfg, positions[:, None])
+    q = _mla_q(x, p, cfg)[:, 0]                                # (B, H, qk)
+    q_rope = apply_rope(q[:, None, :, nope:], cos, sin,
+                        cfg.rope_interleaved)[:, 0].astype(jnp.float32)
+    q_abs = jnp.einsum("bhn,rhn->bhr", q[..., :nope].astype(jnp.float32),
+                       p["w_uk"].astype(jnp.float32))          # (B, H, r)
+    ckv, k_rope = _mla_latents(x, p, cfg, cos, sin)
+    qcat = _mla_rows(q_abs, q_rope, W, jnp.float32)
+    rows = _mla_rows(ckv, k_rope, W, lat.dtype)                # (B, 1, W)
+    o_lat, lat = mla_decode_attention(
+        qcat, rows, lat, block_tables, positions,
+        scale=cfg.mla_softmax_scale, v_width=r, interpret=interpret)
+    o = jnp.einsum("bhr,rhv->bhv", o_lat, p["w_uv"].astype(jnp.float32))
+    out = jnp.einsum("bhv,hvd->bd", o.astype(x.dtype), p["wo"])[:, None, :]
+    return out, {"lat": lat}

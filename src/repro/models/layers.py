@@ -5,10 +5,14 @@ dtype."""
 
 from __future__ import annotations
 
+import math
+
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+
+from repro.configs.base import yarn_get_mscale
 
 
 def rms_norm(x, w, eps: float = 1e-5):
@@ -25,18 +29,56 @@ def silu(x):
 # ---------------------------------------------------------------------------
 # Positions
 # ---------------------------------------------------------------------------
-def rope_tables(positions, head_dim: int, theta: float):
-    """positions: int array (...,) -> cos/sin tables (..., head_dim//2)."""
+def yarn_inv_freq(dim: int, theta: float, factor: float, orig: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's inverse frequencies (HF ``DeepseekV2YarnRotaryEmbedding``):
+    dimensions that turn more than ``beta_fast`` times over the original
+    context keep theta's frequency, those turning fewer than ``beta_slow``
+    times are divided by ``factor``, with a linear ramp between."""
+    def corr_dim(rot):
+        return dim * math.log(orig / (rot * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(corr_dim(beta_fast)), 0)
+    high = min(math.ceil(corr_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = 1.0 / (theta ** (np.arange(0, dim, 2) / dim))
+    inter = extra / factor
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0, 1)
+    keep = 1.0 - ramp
+    return inter * (1 - keep) + extra * keep
+
+
+def rope_tables(positions, head_dim: int, theta: float, cfg=None):
+    """positions: int array (...,) -> cos/sin tables (..., head_dim//2).
+    A ``cfg`` with ``yarn_factor`` set takes YaRN's frequencies and scales
+    the tables by mscale / mscale_all_dim."""
     half = head_dim // 2
-    inv = 1.0 / (theta ** (np.arange(0, half) / half))
-    ang = positions.astype(jnp.float32)[..., None] * inv  # (..., half)
-    return jnp.cos(ang), jnp.sin(ang)
+    scale = 1.0
+    if cfg is not None and cfg.yarn_factor:
+        inv = yarn_inv_freq(head_dim, theta, cfg.yarn_factor,
+                            cfg.yarn_original_max_pos, cfg.yarn_beta_fast,
+                            cfg.yarn_beta_slow)
+        scale = yarn_get_mscale(cfg.yarn_factor, cfg.yarn_mscale) \
+            / yarn_get_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+    else:
+        inv = 1.0 / (theta ** (np.arange(0, half) / half))
+    ang = positions.astype(jnp.float32)[..., None] \
+        * inv.astype(np.float32)                          # (..., half)
+    if scale == 1.0:
+        return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
 
 
-def apply_rope(x, cos, sin):
-    """x: (B, S, H, Dh); cos/sin: (S, Dh/2) or (B, S, Dh/2)."""
+def apply_rope(x, cos, sin, interleaved: bool = False):
+    """x: (B, S, H, Dh); cos/sin: (S, Dh/2) or (B, S, Dh/2).  Half-split
+    rotation; ``interleaved`` first gathers DeepSeek's pairs (x[2i],
+    x[2i+1]) into the halves, as HF's DeepseekV2 ``apply_rotary_pos_emb``
+    does (the output stays in the gathered order)."""
     half = x.shape[-1] // 2
-    x1, x2 = x[..., :half], x[..., half:]
+    if interleaved:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], -1)
     if cos.ndim == 2:        # (S, half) -> (1, S, 1, half)
         cos = cos[None, :, None, :]
         sin = sin[None, :, None, :]

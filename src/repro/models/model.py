@@ -111,8 +111,9 @@ def _init_layer(kg, mixer, ffn, cfg: ModelConfig, stack: int = 0):
         p.update(w_gate=mk(d, cfg.d_ff), w_up=mk(d, cfg.d_ff),
                  w_down=mk(cfg.d_ff, d))
     elif ffn == "moe":
-        E, fe = cfg.num_experts, cfg.d_ff_expert
-        p.update(router=mk(d, E),
+        # the router scores every expert; the weights are the held ones'
+        E, fe = cfg.held_experts, cfg.d_ff_expert
+        p.update(router=mk(d, cfg.num_experts),
                  w_gate=mk(E, d, fe), w_up=mk(E, d, fe), w_down=mk(E, fe, d))
         if cfg.num_shared_experts:
             fs = cfg.num_shared_experts * fe
@@ -247,32 +248,38 @@ class Model:
     # through block tables handed in by the serving engine's BlockManager.
     # ------------------------------------------------------------------
     def supports_paged(self) -> bool:
-        """Paged serving covers pure-attention stacks (any FFN) with rope
-        or no positional encoding — recurrent mixers have no paged state
-        and sinusoidal embeds would need per-sequence position offsets."""
+        """Paged serving covers attention stacks (GQA or latent attention,
+        any FFN) with rope or no positional encoding — recurrent mixers
+        have no paged state and sinusoidal embeds would need per-sequence
+        position offsets."""
         cfg = self.cfg
-        return (all(m == "attn" for m, _ in
+        return (all(m in ("attn", "mla") for m, _ in
                     cfg.prefix_pattern + cfg.unit_pattern)
                 and cfg.positional in ("rope", "none")
                 and cfg.frontend == "none")
 
     def paged_cache_specs(self, num_pages: int, page: int):
-        """Page pools per attention layer: k/v (num_pages, page, KV, Dh);
-        scanned units carry the leading num_units dim like cache_specs."""
+        """Page pools per attention layer — GQA: k/v (num_pages, page, KV,
+        Dh); MLA: one latent pool (num_pages, page, W) of rows [c | k_rope
+        | 0], W = kv_lora_rank + qk_rope_dim rounded up to 128 lanes.
+        Scanned units carry the leading num_units dim like cache_specs."""
+        from repro.models.attention import mla_row_width
         cfg = self.cfg
         KV, hd = cfg.num_kv_heads, cfg.resolved_head_dim
         dt = jnp.dtype(cfg.dtype)
 
-        def kv(stack=0):
-            shape = (num_pages, page, KV, hd)
-            if stack:
-                shape = (stack,) + shape
+        def pool(mixer, stack=0):
+            lead = (stack,) if stack else ()
+            if mixer == "mla":
+                return {"lat": jax.ShapeDtypeStruct(
+                    lead + (num_pages, page, mla_row_width(cfg)), dt)}
+            shape = lead + (num_pages, page, KV, hd)
             return {"k": jax.ShapeDtypeStruct(shape, dt),
                     "v": jax.ShapeDtypeStruct(shape, dt)}
 
-        prefix = tuple(kv() for _ in cfg.prefix_pattern)
-        units = {f"l{i}": kv(stack=cfg.num_units)
-                 for i in range(len(cfg.unit_pattern))}
+        prefix = tuple(pool(m) for m, _ in cfg.prefix_pattern)
+        units = {f"l{i}": pool(m, stack=cfg.num_units)
+                 for i, (m, _) in enumerate(cfg.unit_pattern)}
         return {"prefix": prefix, "units": units}
 
     def init_paged_caches(self, num_pages: int, page: int):
@@ -280,46 +287,51 @@ class Model:
                             self.paged_cache_specs(num_pages, page))
 
     def kv_bytes_per_token(self) -> int:
-        """True per-token KV footprint of this model's paged cache."""
-        cfg = self.cfg
-        n_attn = len(cfg.prefix_pattern) \
-            + cfg.num_units * len(cfg.unit_pattern)
-        return int(2 * cfg.num_kv_heads * cfg.resolved_head_dim
-                   * jnp.dtype(cfg.dtype).itemsize * n_attn)
+        """Bytes a token's cache takes in the page pools, as stored (MLA
+        rows with their lane padding)."""
+        specs = self.paged_cache_specs(1, 1)
+        return int(sum(np.prod(s.shape) * s.dtype.itemsize
+                       for s in jax.tree.leaves(specs)))
 
-    def prefill_paged(self, params, pages, tokens, start, block_table, n):
+    def prefill_paged(self, params, pages, tokens, start, block_table, n, *,
+                      interpret: bool = False):
         """Append one prompt chunk's KV for a single sequence.
 
         tokens: (1, C) with rows past ``n`` as padding; start: tokens
         already resident.  No logits are produced — the first decode step
         re-runs the final prompt token (its KV write is idempotent), so
-        every emitted token flows through decode_paged uniformly."""
+        every emitted token flows through decode_paged uniformly.
+        Returns (new pages, routed (token, held expert) pairs per scanned
+        unit and held expert (num_units, E), or None without MoE units).
+        ``interpret`` runs the MoE kernel interpreted (off a TPU)."""
         x = self._embed(params, {"tokens": tokens}, "prefill")
-        _, new_pages = stack_apply_paged(x, params, self.cfg, self.ctx,
-                                         "prefill", pages, block_table,
-                                         start, n)
-        return new_pages
+        _, new_pages, counts = stack_apply_paged(
+            x, params, self.cfg, self.ctx, "prefill", pages, block_table,
+            start, n, interpret=interpret)
+        return new_pages, counts
 
     def decode_paged(self, params, pages, tokens, positions, block_tables,
                      *, interpret: bool = False, fused: bool = False):
         """One batched decode step: tokens (B,1) i32 at per-sequence write
         positions (B,); block_tables (B, n_max).  Returns (logits (B, V),
-        new pages).  ``fused=True`` routes attention through the
-        single-dispatch append+attend kernel (``fused_decode_attention``).
+        new pages, routed-pair counts or None as in ``prefill_paged``;
+        lanes whose table starts on the scrap page, the pool's last, are
+        padding and route nothing).  ``fused=True`` routes
+        GQA attention through the single-dispatch append+attend kernel
+        (``fused_decode_attention``); MLA always takes its fused kernel.
         Under serving TP (ctx.tp_vocab_axis set) lm_head is
         vocab-column-sharded; the local logit slices are all-gathered —
         a pure concatenation, every column computed exactly as on one
         device — before the vocab-size slice."""
         x = self._embed(params, {"tokens": tokens}, "decode", index=0)
-        x, new_pages = stack_apply_paged(x, params, self.cfg, self.ctx,
-                                         "decode", pages, block_tables,
-                                         positions, interpret=interpret,
-                                         fused=fused)
+        x, new_pages, counts = stack_apply_paged(
+            x, params, self.cfg, self.ctx, "decode", pages, block_tables,
+            positions, interpret=interpret, fused=fused)
         x = rms_norm(x, params["final_norm"], self.cfg.norm_eps)
         logits = jnp.einsum("bd,dv->bv", x[:, 0], params["lm_head"],
                             preferred_element_type=jnp.float32)
         logits = self.ctx.gather_vocab(logits)
-        return logits[..., :self.cfg.vocab_size], new_pages
+        return logits[..., :self.cfg.vocab_size], new_pages, counts
 
     def verify_paged(self, params, pages, tokens, pos0, widths,
                      block_tables, *, interpret: bool = False):
@@ -332,9 +344,13 @@ class Model:
         Each row's logits are bitwise identical to the single-token decode
         at that position (per-row unrolled verification kernel + row-stable
         einsums), which is what makes spec-on streams byte-equal to
-        spec-off."""
+        spec-off.  GQA stacks only."""
+        if any(m != "attn" for m, _ in
+               self.cfg.prefix_pattern + self.cfg.unit_pattern):
+            raise ValueError(f"{self.cfg.name}: speculative verification "
+                             "covers GQA attention only")
         x = self._embed(params, {"tokens": tokens}, "decode", index=0)
-        x, new_pages = stack_apply_paged(x, params, self.cfg, self.ctx,
+        x, new_pages, _ = stack_apply_paged(x, params, self.cfg, self.ctx,
                                          "verify", pages, block_tables,
                                          pos0, n=widths, interpret=interpret)
         x = rms_norm(x, params["final_norm"], self.cfg.norm_eps)

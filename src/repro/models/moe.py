@@ -1,8 +1,13 @@
-"""Mixture-of-Experts with two interchangeable implementations:
+"""Mixture-of-Experts with three implementations:
 
-``moe_dense``  — every expert computed for every token, gated combine.  Used
-                 for tiny CPU smoke tests (E≤8) and as the differentiable
-                 reference oracle in property tests.
+``moe_dense``  — every held expert computed for every token, gated combine.
+                 Used for tiny CPU smoke tests (E≤8) and as the
+                 differentiable reference oracle in property tests.
+``moe_held``   — the paged serving path: the experts this device holds
+                 (``cfg.experts_first`` .., ``cfg.held_experts`` of them),
+                 routed over all ``num_experts``, each computed for exactly
+                 the tokens routed to it by the grouped Pallas kernel
+                 ``moe_gmm``; no capacity, nothing dropped.
 ``moe_ep``     — expert parallelism via `shard_map`: experts sharded over the
                  'model' axis (weights additionally storage-sharded over
                  'data' and gathered at use), tokens dispatched with explicit
@@ -10,7 +15,8 @@
                  sorted-rank slotting.  This is the production path; the a2a
                  bytes are what the roofline's collective term sees.
 
-Both paths share the router (softmax → top-k → renormalise).
+All share the router: softmax → greedy top-k → renormalised unless
+``cfg.norm_topk_prob`` is false → times ``cfg.routed_scaling_factor``.
 """
 
 from __future__ import annotations
@@ -26,14 +32,15 @@ from repro.models.layers import silu
 from jax.sharding import PartitionSpec as P
 
 
-def _route(x2, router_w, top_k):
-    """x2: (T, D) -> (topv, topi) each (T, k), renormalised."""
+def _route(x2, router_w, cfg):
+    """x2: (T, D) -> (topv, topi) each (T, k) over all ``num_experts``."""
     logits = jnp.einsum("td,de->te", x2, router_w,
                         preferred_element_type=jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
-    topv, topi = jax.lax.top_k(probs, top_k)
-    topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
-    return topv, topi
+    topv, topi = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+    return topv * cfg.routed_scaling_factor, topi
 
 
 def _expert_ffn(tokens, wg, wu, wd):
@@ -44,17 +51,74 @@ def _expert_ffn(tokens, wg, wu, wd):
 
 
 def moe_dense(x, p, cfg):
-    """x: (B,S,D).  All-experts reference path."""
+    """x: (B,S,D).  All-held-experts reference path: experts the device
+    does not hold contribute nothing."""
     B, S, D = x.shape
     x2 = x.reshape(B * S, D)
-    topv, topi = _route(x2, p["router"], cfg.top_k)
+    topv, topi = _route(x2, p["router"], cfg)
     g = jnp.einsum("td,edf->tef", x2, p["w_gate"])
     u = jnp.einsum("td,edf->tef", x2, p["w_up"])
     y_all = jnp.einsum("tef,efd->ted", silu(g) * u, p["w_down"])
-    oh = jax.nn.one_hot(topi, cfg.num_experts, dtype=jnp.float32)  # (T,k,E)
+    # one_hot of an expert outside the held range is all zeros
+    oh = jax.nn.one_hot(topi - cfg.experts_first, cfg.held_experts,
+                        dtype=jnp.float32)                        # (T,k,E)
     w = jnp.einsum("tk,tke->te", topv, oh)
     comb = jnp.einsum("ted,te->td", y_all.astype(jnp.float32), w)
     return comb.astype(x.dtype).reshape(B, S, D)
+
+
+def moe_held(x, p, cfg, valid=None, *, interpret: bool = False):
+    """x: (B,S,D).  The held experts' part of the routed output, through
+    ``kernels.moe_gmm``: the (token, expert) pairs the router picks among
+    the held experts (and, where given, ``valid`` (B,S) tokens) are laid
+    out grouped by expert, each group on whole row tiles, and each group
+    is computed by its expert alone.  Returns (y (B,S,D), routed pairs per
+    held expert (E_held,) int32)."""
+    from repro.kernels.moe_gmm import moe_gmm
+    B, S, D = x.shape
+    T, k, E = B * S, cfg.top_k, cfg.held_experts
+    x2 = x.reshape(T, D)
+    topv, topi = _route(x2, p["router"], cfg)
+    loc = topi - cfg.experts_first
+    held = (loc >= 0) & (loc < E)
+    if valid is not None:
+        held = held & valid.reshape(T, 1)
+    flat = jnp.where(held, loc, E).reshape(-1)          # E: not held here
+    counts = jnp.zeros((E + 1,), jnp.int32).at[flat].add(1)
+    order = jnp.argsort(flat, stable=True)
+    grp = flat[order]
+    first = jnp.cumsum(counts) - counts                 # sorted offsets
+    rank = jnp.arange(T * k, dtype=jnp.int32) - first[grp]
+    # row tiles: small for decode batches, MXU-sized for prefill chunks
+    rows = T * min(k, E)
+    tm = 128 if rows >= 1024 else 16
+    tiles = -(-counts[:E] // tm)
+    tile0 = jnp.cumsum(tiles) - tiles
+    max_tiles = -(-rows // tm) + E
+    M = max_tiles * tm
+    dest = jnp.where(grp < E, tile0[jnp.minimum(grp, E - 1)] * tm + rank, M)
+    tok = order // k
+    xs = jnp.zeros((M, D), x.dtype).at[dest].set(x2[tok], mode="drop")
+    tile_group = jnp.minimum(
+        jnp.searchsorted(jnp.cumsum(tiles), jnp.arange(max_tiles),
+                         side="right"), E - 1)
+    # at least one tile: a step that routes nothing here (a lone decode
+    # lane, warm-up on the scrap page) computes an unread tile rather than
+    # launch an empty grid
+    y = moe_gmm(xs, p["w_gate"], p["w_up"], p["w_down"], tile_group,
+                jnp.maximum(jnp.sum(tiles), 1), tm=tm, interpret=interpret)
+    w = topv.reshape(-1)[order]
+    ys = jnp.where((grp < E)[:, None],
+                   y[jnp.minimum(dest, M - 1)].astype(jnp.float32)
+                   * w[:, None], 0.0)
+    out = jnp.zeros((T, D), jnp.float32).at[tok].add(ys)
+    return out.astype(x.dtype).reshape(B, S, D), counts[:E]
+
+
+def shared_experts(x, p, cfg):
+    """The shared experts, one SwiGLU MLP of their summed width."""
+    h = silu(x @ p["shared_gate"]) * (x @ p["shared_up"])
+    return h @ p["shared_down"]
 
 
 def _ep_local(x_local, router_w, wg, wu, wd, *, cfg, ep_axis, ep_size,
@@ -88,7 +152,7 @@ def _ep_local(x_local, router_w, wg, wu, wd, *, cfg, ep_axis, ep_size,
         x2 = jax.lax.all_gather(x2, gather_axis, axis=0, tiled=True)
     T = x2.shape[0]
     C = max(1, math.ceil(T * k / E * cfg.capacity_factor))
-    topv, topi = _route(x2, router_w, k)
+    topv, topi = _route(x2, router_w, cfg)
 
     flat_e = topi.reshape(-1)                            # (T*k,)
     tok = jnp.arange(T * k) // k
@@ -164,12 +228,12 @@ def _spec_for(ctx, shape):
 
 def moe_apply(x, p, cfg, ctx):
     """Full MoE block: routed experts (+ shared experts)."""
-    if ctx.ep and ctx.mesh is not None and \
-            cfg.num_experts % ctx.mesh.shape[ctx.ep_axis] == 0:
+    if ctx.ep and ctx.mesh is not None \
+            and cfg.held_experts == cfg.num_experts \
+            and cfg.num_experts % ctx.mesh.shape[ctx.ep_axis] == 0:
         y = moe_ep(x, p, cfg, ctx)
     else:
         y = moe_dense(x, p, cfg)
     if cfg.num_shared_experts:
-        h = silu(x @ p["shared_gate"]) * (x @ p["shared_up"])
-        y = y + h @ p["shared_down"]
+        y = y + shared_experts(x, p, cfg)
     return y

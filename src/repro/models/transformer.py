@@ -9,10 +9,11 @@ import jax.numpy as jnp
 
 from repro.models.attention import (gqa_apply, gqa_decode_paged,
                                     gqa_prefill_paged, gqa_verify_paged,
-                                    mla_apply)
+                                    mla_apply, mla_decode_paged,
+                                    mla_prefill_paged)
 from repro.models.layers import mlp, rms_norm
 from repro.models.mamba import mamba_apply
-from repro.models.moe import moe_apply
+from repro.models.moe import moe_apply, moe_held, shared_experts
 from repro.models.xlstm import mlstm_apply, slstm_apply
 
 MIXERS = {
@@ -84,11 +85,25 @@ def stack_apply(x, params, cfg, ctx, mode, caches=None, index=None):
 # ---------------------------------------------------------------------------
 def layer_apply_paged(x, lp, mixer, ffn, cfg, ctx, mode, pages, tables, pos,
                       n=None, interpret=False, fused=False):
-    if mixer != "attn":
+    """One layer over its page pool.  Returns (x, new pages, routed pairs
+    per held expert (E,) int32 for an MoE layer, else None).  MoE layers
+    take the held-expert path (``moe_held``) and route only real tokens:
+    prefill rows before ``n``, decode lanes whose table does not start on
+    the scrap page (the pool's last)."""
+    if mixer not in ("attn", "mla"):
         raise ValueError(
-            f"paged serving supports 'attn' mixers only, got {mixer!r}")
+            f"paged serving supports 'attn' and 'mla' mixers, got {mixer!r}")
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-    if mode == "prefill":
+    if mixer == "mla":
+        if mode == "prefill":
+            mix_out, new_pages = mla_prefill_paged(h, lp, cfg, pages, tables,
+                                                   pos, n)
+        elif mode == "decode":
+            mix_out, new_pages = mla_decode_paged(h, lp, cfg, pages, tables,
+                                                  pos, interpret=interpret)
+        else:
+            raise ValueError(f"latent attention has no paged {mode!r} path")
+    elif mode == "prefill":
         mix_out, new_pages = gqa_prefill_paged(h, lp, cfg, pages, tables,
                                                pos, n, ctx=ctx)
     elif mode == "verify":
@@ -102,11 +117,22 @@ def layer_apply_paged(x, lp, mixer, ffn, cfg, ctx, mode, pages, tables, pos,
                                               pos, interpret=interpret,
                                               ctx=ctx, fused=fused)
     x = ctx.hidden(x + mix_out)
-    if ffn != "none":
+    counts = None
+    if ffn == "moe":
         h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        y = mlp(h2, lp, ctx) if ffn == "mlp" else moe_apply(h2, lp, cfg, ctx)
+        scrap = jax.tree.leaves(pages)[0].shape[0] - 1
+        if mode == "prefill":
+            valid = (jnp.arange(x.shape[1]) < n)[None, :]
+        else:
+            valid = (tables[:, 0] != scrap)[:, None]
+        y, counts = moe_held(h2, lp, cfg, valid, interpret=interpret)
+        if cfg.num_shared_experts:
+            y = y + shared_experts(h2, lp, cfg)
         x = ctx.hidden(x + y)
-    return x, new_pages
+    elif ffn != "none":
+        h2 = rms_norm(x, lp["ln2"], cfg.norm_eps)
+        x = ctx.hidden(x + mlp(h2, lp, ctx))
+    return x, new_pages, counts
 
 
 def stack_apply_paged(x, params, cfg, ctx, mode, pages, tables, pos, n=None,
@@ -117,25 +143,31 @@ def stack_apply_paged(x, params, cfg, ctx, mode, pages, tables, pos, n=None,
     ``tables`` is (B, n_max), ``pos`` the per-sequence write positions (B,).
     mode "verify" (speculative decoding, DESIGN.md §11): x is (B, W, D)
     window hidden states, ``pos`` the per-lane first-row positions (B,),
-    ``n`` the per-lane live widths (B,).  Returns (x, new pages pytree)."""
+    ``n`` the per-lane live widths (B,).  Returns (x, new pages pytree,
+    routed pairs (num_units, E) of the scanned units' MoE layers, summed
+    over a unit's MoE layers, or None)."""
     new_prefix = []
     for i, (mixer, ffn) in enumerate(cfg.prefix_pattern):
-        x, np_ = layer_apply_paged(x, params["prefix"][f"l{i}"], mixer, ffn,
-                                   cfg, ctx, mode, pages["prefix"][i],
-                                   tables, pos, n, interpret, fused)
+        x, np_, _ = layer_apply_paged(x, params["prefix"][f"l{i}"], mixer,
+                                      ffn, cfg, ctx, mode,
+                                      pages["prefix"][i], tables, pos, n,
+                                      interpret, fused)
         new_prefix.append(np_)
 
     def body(carry, xs):
         up, upages = xs
         h = carry
-        new_u = {}
+        new_u, cnt = {}, None
         for i, (mixer, ffn) in enumerate(cfg.unit_pattern):
             key = f"l{i}"
-            h, nc = layer_apply_paged(h, up[key], mixer, ffn, cfg, ctx, mode,
-                                      upages[key], tables, pos, n, interpret,
-                                      fused)
+            h, nc, c = layer_apply_paged(h, up[key], mixer, ffn, cfg, ctx,
+                                         mode, upages[key], tables, pos, n,
+                                         interpret, fused)
             new_u[key] = nc
-        return h, new_u
+            if c is not None:
+                cnt = c if cnt is None else cnt + c
+        return h, (new_u, cnt)
 
-    x, unit_pages = jax.lax.scan(body, x, (params["units"], pages["units"]))
-    return x, {"prefix": tuple(new_prefix), "units": unit_pages}
+    x, (unit_pages, counts) = jax.lax.scan(
+        body, x, (params["units"], pages["units"]))
+    return x, {"prefix": tuple(new_prefix), "units": unit_pages}, counts

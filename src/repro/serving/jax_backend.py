@@ -129,6 +129,12 @@ class PagedJaxBackend(Backend):
         # (e.g. ``get_config(name)``, the published widths) is served as is
         self.cfg = reduced_config(arch) if isinstance(arch, str) else arch
         self.tp = max(int(tp), 1)
+        mla = any(m == "mla" for m, _ in
+                  self.cfg.prefix_pattern + self.cfg.unit_pattern)
+        if mla and self.tp > 1:
+            raise ValueError(f"{self.cfg.name}: latent attention serves on "
+                             "one device (tp=1); its pool has no head dim "
+                             "to shard")
         self.plan = paged_tp_plan(self.cfg, self.tp)
         if self.tp > 1:
             devs = list(devices) if devices else jax.devices()
@@ -145,8 +151,13 @@ class PagedJaxBackend(Backend):
         self.model = build_model(self.cfg, ctx)
         if not self.model.supports_paged():
             raise ValueError(
-                f"{arch}: paged serving needs a pure-attention stack with "
-                "rope/none positions (recurrent mixers have no paged state)")
+                f"{arch}: paged serving needs an attention stack (GQA or "
+                "latent) with rope/none positions (recurrent mixers have "
+                "no paged state)")
+        # speculative verification has a GQA kernel only
+        self.supports_spec_decode = not mla
+        # routed-pair counts the MoE programs returned, read at sync
+        self._counts_pending: List = []
         self.page = page
         self.max_len = max_len
         self.n_max = -(-max_len // page)         # block-table width
@@ -190,7 +201,9 @@ class PagedJaxBackend(Backend):
         if self.mesh is None:
             self.params = self.model.init(key)
             self.pages = self.model.init_paged_caches(pool + 1, page)
-            self._prefill = jax.jit(self.model.prefill_paged)
+            self._prefill = jax.jit(_named(
+                "prefill_paged", self.model.prefill_paged,
+                interpret=self.interpret))
             self._prefill_many = jax.jit(self._prefill_many_impl)
             # one decode step returning logits (host sampling) — the
             # roofline profile and logit checks read it
@@ -224,6 +237,15 @@ class PagedJaxBackend(Backend):
             "jax_compiles_total",
             "XLA compiles and persistent-cache loads the dispatches "
             "triggered")
+        self._m_routed = {ph: obs.counter(
+            "jax_moe_routed_total",
+            "(token, held expert) pairs the router sent to this device's "
+            "experts, summed over MoE layers", phase=ph)
+            for ph in ("prefill", "decode")}
+        self._m_moe_tokens = {ph: obs.counter(
+            "jax_moe_tokens_total",
+            "tokens that passed the MoE layers (each counted once)",
+            phase=ph) for ph in ("prefill", "decode")}
 
     def _build_sharded_step_fns(self, key, n_pages: int) -> None:
         """Resident-sharded weights and page pool, and jit(shard_map(...))
@@ -252,20 +274,21 @@ class PagedJaxBackend(Backend):
             functools.partial(self.model.init_paged_caches, n_pages,
                               self.page),
             out_shardings=self._page_shardings)()
+        # MoE experts stay replicated under tp, so routed counts are too
         self._prefill = jax.jit(jax.shard_map(
             self.model.prefill_paged, mesh=self.mesh,
             in_specs=(pspecs, gspecs, P(), P(), P(), P()),
-            out_specs=gspecs, check_vma=False))
+            out_specs=(gspecs, P()), check_vma=False))
         self._prefill_many = jax.jit(jax.shard_map(
             self._prefill_many_impl, mesh=self.mesh,
             in_specs=(pspecs, gspecs, P(), P(), P(), P()),
-            out_specs=gspecs, check_vma=False))
+            out_specs=(gspecs, P()), check_vma=False))
         self._decode = jax.jit(jax.shard_map(
             _named("decode_step", self.model.decode_paged,
                    interpret=self.interpret, fused=self.fused),
             mesh=self.mesh,
             in_specs=(pspecs, gspecs, P(), P(), P()),
-            out_specs=(P(), gspecs), check_vma=False))
+            out_specs=(P(), gspecs, P()), check_vma=False))
 
     def _commit_pages(self) -> None:
         """Re-pin the pool's sharding after a host-side page mutation
@@ -295,18 +318,19 @@ class PagedJaxBackend(Backend):
             pages, toks, pos, rem = carry
             active = rem > 0
             tabs_eff = jnp.where(active[:, None], tabs, scrap_row)
-            logits, pages = self.model.decode_paged(
+            logits, pages, cnt = self.model.decode_paged(
                 params, pages, toks, pos, tabs_eff,
                 interpret=self.interpret, fused=self.fused)
             nxt = self.sampler.sample_device(logits, rids, pos)
             toks = jnp.where(active, nxt, toks[:, 0])[:, None]
             pos = pos + active.astype(pos.dtype)
             rem = rem - active.astype(rem.dtype)
-            return (pages, toks, pos, rem), (nxt, active)
+            return (pages, toks, pos, rem), (nxt, active, cnt)
 
-        (pages, _, _, _), (tok_n, act_n) = jax.lax.scan(
+        (pages, _, _, _), (nxt, active, cnt) = jax.lax.scan(
             micro, (pages, toks, pos, rem), None, length=n)
-        return tok_n.T, act_n.T, pages          # (B, n) each
+        # (B, n) tokens and active flags; MoE: routed pairs summed over n
+        return nxt.T, active.T, pages, jax.tree.map(lambda c: c.sum(0), cnt)
 
     def _decode_n_fn(self, n: int):
         """Jitted (and, under tp, shard_mapped) scan dispatch for a given
@@ -322,7 +346,8 @@ class PagedJaxBackend(Backend):
                     body, mesh=self.mesh,
                     in_specs=(self._pspecs, self._gspecs,
                               P(), P(), P(), P(), P()),
-                    out_specs=(P(), P(), self._gspecs), check_vma=False))
+                    out_specs=(P(), P(), self._gspecs, P()),
+                    check_vma=False))
             self._decode_n_cache[n] = fn
         return fn
 
@@ -333,10 +358,11 @@ class PagedJaxBackend(Backend):
         tables, and their discarded activations never touch the pool."""
         def body(pages, xs):
             t, s, tab, n = xs
-            return self.model.prefill_paged(params, pages, t, s, tab, n), None
+            return self.model.prefill_paged(params, pages, t, s, tab, n,
+                                            interpret=self.interpret)
 
-        pages, _ = jax.lax.scan(body, pages, (toks, starts, tabs, ns))
-        return pages
+        pages, cnt = jax.lax.scan(body, pages, (toks, starts, tabs, ns))
+        return pages, jax.tree.map(lambda c: c.sum(0), cnt)
 
     @contextlib.contextmanager
     def _launch(self):
@@ -416,6 +442,7 @@ class PagedJaxBackend(Backend):
         self._prompts.clear()
         self._host.clear()
         self._pf_queue.clear()
+        self._counts_pending.clear()
         self._tab_cache.clear()
         self.n_decode_dispatches = 0
         self.n_decode_tokens = 0
@@ -475,8 +502,12 @@ class PagedJaxBackend(Backend):
                 calls.append((self._prefill_many, (toksL, starts, tabsL, ns)))
         with self._launch():
             for fn, args in calls:
-                self.pages = fn(self.params, self.pages,
-                                *(jnp.asarray(a) for a in args))
+                self.pages, cnt = fn(self.params, self.pages,
+                                     *(jnp.asarray(a) for a in args))
+                if cnt is not None:
+                    self._counts_pending.append(cnt)
+        if cnt is not None:         # an MoE stack: every call counts
+            self._m_moe_tokens["prefill"].inc(sum(item[4] for item in q))
         self.n_prefill_dispatches += len(calls)
         self._t_acc += time.perf_counter() - t0
 
@@ -522,17 +553,21 @@ class PagedJaxBackend(Backend):
                 rids[i] = r.rid & 0x7FFFFFFF
         t0 = time.perf_counter()
         with self._launch():
-            tok_n, act_n, self.pages = self._decode_n_fn(n)(
+            tok_n, act_n, self.pages, cnt = self._decode_n_fn(n)(
                 self.params, self.pages, jnp.asarray(toks),
                 jnp.asarray(pos), jnp.asarray(tabs), jnp.asarray(rem),
                 jnp.asarray(rids))
         with span("backend.wait"):
             tok_n = np.asarray(tok_n)       # ONE host sync per n tokens
             act_n = np.asarray(act_n)
+            cnt = None if cnt is None else np.asarray(cnt)
         self._t_acc += time.perf_counter() - t0
         self.n_decode_dispatches += 1
         with span("backend.unpack"):
             self.n_decode_tokens += int(act_n[:nr].sum())
+            if cnt is not None:
+                self._m_routed["decode"].inc(int(cnt.sum()))
+                self._m_moe_tokens["decode"].inc(int(act_n[:nr].sum()))
             for i, r in enumerate(reqs):
                 gen = self.generated[r.rid]
                 for s in range(n):
@@ -663,14 +698,27 @@ class PagedJaxBackend(Backend):
         return out
 
     # -- KV residency hooks (mirror BlockManager transitions 1:1) -------
-    def _gather(self, leaf, table):
-        return leaf[:, table] if leaf.ndim == 5 else leaf[table]
+    @staticmethod
+    def _page_axis(path) -> int:
+        """Where the page index sits in a pool leaf: after the layer dim
+        for the scanned units' stacked pools, first otherwise (whatever
+        a pool's row shape)."""
+        return 1 if getattr(path[0], "key", None) == "units" else 0
 
-    def _scatter(self, leaf, table, saved):
-        saved = jnp.asarray(saved, leaf.dtype)
-        if leaf.ndim == 5:
-            return leaf.at[:, table].set(saved)
-        return leaf.at[table].set(saved)
+    def _gather_pages(self, table):
+        """Host copies of the pages ``table`` lists, in every pool."""
+        return jax.tree_util.tree_map_with_path(
+            lambda path, p: np.asarray(jnp.take(p, table,
+                                                axis=self._page_axis(path))),
+            self.pages)
+
+    def _scatter_pages(self, table, saved) -> None:
+        def put(path, p, s):
+            idx = (slice(None),) * self._page_axis(path) + (table,)
+            return p.at[idx].set(jnp.asarray(s, p.dtype))
+
+        self.pages = jax.tree_util.tree_map_with_path(put, self.pages, saved)
+        self._commit_pages()
 
     def kv_swap_out(self, rid: int, block_table: List[int],
                     tokens: int) -> None:
@@ -678,27 +726,26 @@ class PagedJaxBackend(Backend):
         if not block_table:
             return
         self._flush_prefill()     # the gather must see this step's writes
-        table = np.asarray(block_table, np.int32)
-        self._host[rid] = jax.tree.map(
-            lambda p: np.asarray(self._gather(p, table)), self.pages)
+        self._host[rid] = self._gather_pages(
+            np.asarray(block_table, np.int32))
 
     def kv_swap_in(self, rid: int, block_table: List[int]) -> None:
         saved = self._host.pop(rid, None)
         if saved is None:
             return
-        table = np.asarray(block_table, np.int32)
-        self.pages = jax.tree.map(
-            lambda p, s: self._scatter(p, table, s), self.pages, saved)
-        self._commit_pages()
+        self._scatter_pages(np.asarray(block_table, np.int32), saved)
 
     def kv_copy_page(self, src: int, dst: int) -> None:
         """COW fork: duplicate device page src into dst (the engine is
         about to append into a previously shared page).  Byte-exact copy,
         so forked continuations equal their cache-off counterparts."""
         self._flush_prefill()     # src must hold this step's writes
-        self.pages = jax.tree.map(
-            lambda p: (p.at[:, dst].set(p[:, src]) if p.ndim == 5
-                       else p.at[dst].set(p[src])), self.pages)
+
+        def copy(path, p):
+            lead = (slice(None),) * self._page_axis(path)
+            return p.at[lead + (dst,)].set(p[lead + (src,)])
+
+        self.pages = jax.tree_util.tree_map_with_path(copy, self.pages)
         self._commit_pages()
 
     def kv_release(self, rid: int) -> None:
@@ -720,9 +767,7 @@ class PagedJaxBackend(Backend):
         followers still match the prefill this replica paid for."""
         self._flush_prefill()     # the gather must see this step's writes
         if block_table:
-            table = np.asarray(block_table, np.int32)
-            pages = jax.tree.map(
-                lambda p: np.asarray(self._gather(p, table)), self.pages)
+            pages = self._gather_pages(np.asarray(block_table, np.int32))
         else:
             # swapped-out at export time: the host copy IS the content
             pages = self._host.get(rid)
@@ -750,10 +795,7 @@ class PagedJaxBackend(Backend):
         if pages is None:
             return
         if block_table:
-            table = np.asarray(block_table, np.int32)
-            self.pages = jax.tree.map(
-                lambda p, s: self._scatter(p, table, s), self.pages, pages)
-            self._commit_pages()
+            self._scatter_pages(np.asarray(block_table, np.int32), pages)
         else:
             self._host[rid] = pages
 
@@ -774,5 +816,9 @@ class PagedJaxBackend(Backend):
         with span("backend.wait"):
             jax.tree.leaves(self.pages)[0].block_until_ready()
         self._t_acc += time.perf_counter() - t0
+        if self._counts_pending:
+            self._m_routed["prefill"].inc(
+                int(sum(np.asarray(c).sum() for c in self._counts_pending)))
+            self._counts_pending = []
         self._m_pages.inc(self._pages_step)
         return self.overhead + self._t_acc
